@@ -175,4 +175,6 @@ def run(verbose: bool = True) -> List[Row]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

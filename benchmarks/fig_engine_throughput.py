@@ -793,16 +793,16 @@ def run_wall_stream(verbose: bool = True, tiny: bool = False) -> List[Row]:
     from repro.serving.runtime import ServingRuntime
     from repro.sim.cluster import make_cluster
 
-    arch = "llama3.2-1b"
+    cfg = ARCHS["llama3.2-1b"].reduced()
+    arch, vocab = cfg.name, cfg.vocab
     n_reqs = 8 if tiny else 32
     max_new = WS_MAX_NEW_TINY if tiny else WS_MAX_NEW
     ecfg = EngineExecutorConfig(max_batch=4, max_len=WS_MAX_LEN,
                                 decode_block=WS_DECODE_BLOCK)
-    c = make_cluster(n_accel=1, n_cpu=0, archs=[ARCHS[arch]],
+    c = make_cluster(n_accel=1, n_cpu=0, archs=[cfg],
                      backend="real", clock="wall", engine_cfg=ecfg)
     rt = ServingRuntime(c)
     rng = np.random.default_rng(0)
-    vocab = ARCHS[arch].reduced().vocab
 
     def spec():
         prompt = rng.integers(
@@ -894,10 +894,10 @@ def run_chaos(verbose: bool = True, tiny: bool = False) -> List[Row]:
     from repro.serving.runtime import ServingRuntime
     from repro.sim.cluster import make_cluster
 
-    arch = "llama3.2-1b"
+    cfg = ARCHS["llama3.2-1b"].reduced()
+    arch, vocab = cfg.name, cfg.vocab
     n_reqs = CZ_N_REQS_TINY if tiny else CZ_N_REQS
     max_new = CZ_MAX_NEW_TINY if tiny else CZ_MAX_NEW
-    vocab = ARCHS[arch].reduced().vocab
 
     def phase(faults):
         """Serve the same seeded stream through the wall-clock control
@@ -905,7 +905,7 @@ def run_chaos(verbose: bool = True, tiny: bool = False) -> List[Row]:
         ecfg = EngineExecutorConfig(
             max_batch=4, max_len=CZ_MAX_LEN, decode_block=CZ_DECODE_BLOCK,
             faults=faults)
-        c = make_cluster(n_accel=1, n_cpu=0, archs=[ARCHS[arch]],
+        c = make_cluster(n_accel=1, n_cpu=0, archs=[cfg],
                          backend="real", clock="wall", engine_cfg=ecfg,
                          autoscale=False)
         rt = ServingRuntime(c)
@@ -957,7 +957,6 @@ def run_chaos(verbose: bool = True, tiny: bool = False) -> List[Row]:
     retried = sum(1 for r in chaos_results if r.attempts > 1)
 
     # -- swap vs recompute recovery latency (engine level, deterministic)
-    cfg = ARCHS[arch].reduced()
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
 
@@ -1284,6 +1283,8 @@ if __name__ == "__main__":
     ap.add_argument("--tiny", action="store_true",
                     help="small shapes for CI smoke runs")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.scenario in ("classic", "all"):
         run()
     if args.scenario in ("long_tail", "all"):

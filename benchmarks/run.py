@@ -28,6 +28,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated module substring filter")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     mods = MODULES
     if args.only:
         keys = args.only.split(",")

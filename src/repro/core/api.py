@@ -242,6 +242,9 @@ class QueryResult:
     # past its SLO deadline. ``outputs`` then holds the tokens decoded
     # before the cutoff (possibly none); ``slo_met`` is False.
     timed_out: bool = False
+    # "Type: message" of the latest executor exception behind a failed
+    # attempt ("" if none); the worker logs its traceback
+    error: str = ""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -457,7 +460,7 @@ class QueryHandle:
                 slo=None, slo_met=None, variant=j.variant, worker="",
                 processed=j.processed, total=j.total_inputs,
                 degraded=j.degraded, attempts=j.attempts,
-                timed_out=False)
+                timed_out=False, error=j.error)
         q = self.query
         queue, load, compute = self.breakdown
         return QueryResult(
@@ -467,7 +470,7 @@ class QueryHandle:
             slo=q.slo, slo_met=self.slo_met,
             variant=q.variant, worker=q.worker,
             degraded=q.degraded, attempts=q.attempts,
-            timed_out=q.timed_out)
+            timed_out=q.timed_out, error=q.error)
 
     @property
     def breakdown(self) -> Tuple[float, float, float]:

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import threading
 from collections import deque
 from typing import (Any, Callable, Deque, Dict, List, Optional, Protocol,
@@ -33,6 +34,8 @@ from repro.core.metadata import InstanceState, MetadataStore
 from repro.core.repository import ModelRepository
 from repro.sim import hardware as HW
 from repro.sim.clock import Clock
+
+log = logging.getLogger(__name__)
 
 
 def _locked(fn):
@@ -102,6 +105,9 @@ class Query:
     # wall time of the query's first streamed tokens (-1 until then);
     # first_token - arrival is the query's TTFT
     first_token: float = -1.0
+    # "Type: message" of the latest executor exception that failed one of
+    # this query's attempts ("" if none did); survives the retry path
+    error: str = ""
 
     @property
     def latency(self) -> float:
@@ -123,6 +129,7 @@ class OfflineJob:
     failed: bool = False            # no capacity after max_retries
     attempts: int = 0               # placement attempts (backoff between)
     degraded: bool = False          # any chunk recovered from a preempt
+    error: str = ""                 # latest executor exception, as Query
     done_cb: Optional[Callable[["OfflineJob"], None]] = None
 
     @property
@@ -483,11 +490,11 @@ class Worker:
         # is scheduled that far into the future
         try:
             job.duration = self._service_time(job)
-        except Exception:
+        except Exception as e:  # noqa: BLE001 - reported by _fail_job
             # a bad batch (e.g. a payload exceeding the real engine's
             # max_len) must not escape into the event loop and wedge the
             # device slot: fail the work, keep the slot usable
-            self._fail_job(dev, job)
+            self._fail_job(dev, job, e)
             return
         dev.active += 1
         now = self.loop.now()
@@ -520,7 +527,7 @@ class Worker:
                     with self._lock:
                         dev.active -= 1
                         dev.running.discard(job)
-                        self._fail_job(dev, job)
+                        self._fail_job(dev, job, error)
                     return
                 job.duration = duration
                 self._complete(dev, job)
@@ -529,25 +536,32 @@ class Worker:
         try:
             run_async(job.instance.variant, job.batch,
                       job.requests or None, on_done)
-        except Exception:
+        except Exception as e:  # noqa: BLE001 - reported by _fail_job
             dev.active -= 1
             dev.running.discard(job)
-            self._fail_job(dev, job)
+            self._fail_job(dev, job, e)
 
     @_locked
-    def _fail_job(self, dev: _Device, job: _Job) -> None:
-        """Executor rejected the batch before it started: surface failure
-        (the master's retry path owns what happens next) and keep the
-        device draining."""
+    def _fail_job(self, dev: _Device, job: _Job,
+                  error: BaseException) -> None:
+        """The executor raised on this batch: log the traceback, stamp
+        the exception on the job's queries (``QueryResult.error``), fail
+        them (the master's retry path owns what happens next) and keep
+        the device draining."""
+        log.error("%s: %s job failed", self.name,
+                  job.instance.variant.name, exc_info=error)
+        msg = f"{type(error).__name__}: {error}"
         li = job.instance
         if job.offline_job is None:
             li.outstanding -= 1
             for q in job.queries:
                 q.failed = True
+                q.error = msg
                 if q.done_cb:
                     q.done_cb(q)
         else:
             job.offline_job.failed = True
+            job.offline_job.error = msg
             if job.offline_job in self.offline_jobs:
                 # drop it, or _pump_offline would retry the poisoned
                 # chunk on every monitor tick forever

@@ -131,43 +131,73 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     )(valid_len, q, k, v)
 
 
+def _online_softmax_step(q, k, v, t_start, valid_len, m_scr, l_scr,
+                         acc_scr, h, sm_scale):
+    """Fold one (ps, D) KV tile into head ``h``'s running (m, l, acc)."""
+    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+    t_idx = t_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(t_idx < valid_len, s, NEG_INF)      # (G, ps)
+    m_prev = m_scr[h]                                 # (G, 1)
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[h] = acc_scr[h] * alpha + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
+    m_scr[h] = m_new
+
+
+def _init_scratch(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _finish_heads(o_ref, l_scr, acc_scr):
+    lsum = jnp.maximum(l_scr[...], 1e-30)
+    o_ref[0] = (acc_scr[...] / lsum).astype(o_ref.dtype)
+
+
+def _paged_scratch(K: int, G: int, D: int):
+    return [pltpu.VMEM((K, G, 1), jnp.float32),
+            pltpu.VMEM((K, G, 1), jnp.float32),
+            pltpu.VMEM((K, G, D), jnp.float32)]
+
+
+# The paged kernels block a whole page, all K heads: (1, page_size, K, D).
+# A one-head block (1, page_size, 1, D) would put a block of 1 on the K
+# axis, second-to-last in the pool, which Mosaic refuses unless K == 1 (the
+# last two block dims must tile (8, 128) or span the array). Heads are
+# iterated inside the kernel instead, one MXU pass each, so every page is
+# fetched once per slot in a single DMA.
+
+
 def _paged_decode_kernel(vlen_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
                          m_scr, l_scr, acc_scr, *,
                          page_size: int, n_t_blocks: int, sm_scale: float):
     b = pl.program_id(0)
-    tj = pl.program_id(2)
+    tj = pl.program_id(1)
 
     @pl.when(tj == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _init_scratch(m_scr, l_scr, acc_scr)
 
     t_start = tj * page_size
     valid_len = vlen_ref[b]
 
     @pl.when(t_start < valid_len)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)       # (ps, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)       # (ps, D)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-        t_idx = t_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(t_idx < valid_len, s, NEG_INF)  # (G, ps)
-        m_prev = m_scr[...]                          # (G, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        for h in range(q_ref.shape[1]):
+            q = q_ref[0, h].astype(jnp.float32)           # (G, D)
+            k = k_ref[0, :, h].astype(jnp.float32)        # (ps, D)
+            v = v_ref[0, :, h].astype(jnp.float32)        # (ps, D)
+            _online_softmax_step(q, k, v, t_start, valid_len,
+                                 m_scr, l_scr, acc_scr, h, sm_scale)
 
     @pl.when(tj == n_t_blocks - 1)
     def _finish():
-        lsum = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / lsum).astype(o_ref.dtype)
+        _finish_heads(o_ref, l_scr, acc_scr)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -197,27 +227,22 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 
     kernel = functools.partial(_paged_decode_kernel, page_size=page_size,
                                n_t_blocks=P, sm_scale=D ** -0.5)
-    grid = (B, K, P)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
+            grid=(B, P),
             in_specs=[
-                pl.BlockSpec((1, 1, G, D),
-                             lambda b, h, j, vlen, bt: (b, h, 0, 0)),
-                pl.BlockSpec((1, page_size, 1, D),
-                             lambda b, h, j, vlen, bt: (bt[b, j], 0, h, 0)),
-                pl.BlockSpec((1, page_size, 1, D),
-                             lambda b, h, j, vlen, bt: (bt[b, j], 0, h, 0)),
+                pl.BlockSpec((1, K, G, D),
+                             lambda b, j, vlen, bt: (b, 0, 0, 0)),
+                pl.BlockSpec((1, page_size, K, D),
+                             lambda b, j, vlen, bt: (bt[b, j], 0, 0, 0)),
+                pl.BlockSpec((1, page_size, K, D),
+                             lambda b, j, vlen, bt: (bt[b, j], 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, G, D),
-                                   lambda b, h, j, vlen, bt: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, D), jnp.float32),
-            ],
+            out_specs=pl.BlockSpec((1, K, G, D),
+                                   lambda b, j, vlen, bt: (b, 0, 0, 0)),
+            scratch_shapes=_paged_scratch(K, G, D),
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
         interpret=interpret,
@@ -231,56 +256,48 @@ def _fused_paged_decode_kernel(vlen_ref, wblk_ref, woff_ref, bt_ref,
                                page_size: int, n_t_blocks: int,
                                sm_scale: float):
     b = pl.program_id(0)
-    tj = pl.program_id(2)
+    tj = pl.program_id(1)
 
     @pl.when(tj == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _init_scratch(m_scr, l_scr, acc_scr)
 
     t_start = tj * page_size
     valid_len = vlen_ref[b]
     is_w = tj == wblk_ref[b]
-
     # Inject the new token's k/v row into this tile when it is the write
     # block, then attend over the *updated* tile: the row is visible to
     # the very score pass that needs it (valid_len == pos + 1 covers it)
     # without ever round-tripping HBM.
     sel = (lax.broadcasted_iota(jnp.int32, (page_size, 1), 0)
            == woff_ref[b]) & is_w
-    k = jnp.where(sel, kn_ref[0, 0].astype(jnp.float32),
-                  kp_ref[0, :, 0].astype(jnp.float32))     # (ps, D)
-    v = jnp.where(sel, vn_ref[0, 0].astype(jnp.float32),
-                  vp_ref[0, :, 0].astype(jnp.float32))
 
     @pl.when(is_w)
     def _flush():
-        # the pool outputs' index maps pin every j of this (b, h) to the
+        # the pool outputs' index maps pin every j of this slot to the
         # write page, so this single store is what the one flush carries
-        ko_ref[0, :, 0] = k.astype(ko_ref.dtype)
-        vo_ref[0, :, 0] = v.astype(vo_ref.dtype)
+        sel3 = sel[:, :, None]
+        ko_ref[0] = jnp.where(sel3, kn_ref[0].astype(jnp.float32),
+                              kp_ref[0].astype(jnp.float32)
+                              ).astype(ko_ref.dtype)
+        vo_ref[0] = jnp.where(sel3, vn_ref[0].astype(jnp.float32),
+                              vp_ref[0].astype(jnp.float32)
+                              ).astype(vo_ref.dtype)
 
     @pl.when(t_start < valid_len)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-        t_idx = t_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(t_idx < valid_len, s, NEG_INF)  # (G, ps)
-        m_prev = m_scr[...]                          # (G, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        for h in range(q_ref.shape[1]):
+            k = jnp.where(sel, kn_ref[0, 0, pl.ds(h, 1)].astype(jnp.float32),
+                          kp_ref[0, :, h].astype(jnp.float32))  # (ps, D)
+            v = jnp.where(sel, vn_ref[0, 0, pl.ds(h, 1)].astype(jnp.float32),
+                          vp_ref[0, :, h].astype(jnp.float32))
+            q = q_ref[0, h].astype(jnp.float32)           # (G, D)
+            _online_softmax_step(q, k, v, t_start, valid_len,
+                                 m_scr, l_scr, acc_scr, h, sm_scale)
 
     @pl.when(tj == n_t_blocks - 1)
     def _finish():
-        lsum = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / lsum).astype(o_ref.dtype)
+        _finish_heads(o_ref, l_scr, acc_scr)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -313,52 +330,34 @@ def fused_paged_decode_attention(q: jax.Array, k_new: jax.Array,
     wblk = jnp.clip(pos // page_size, 0, P - 1)
     woff = pos % page_size
     bt = jnp.clip(block_table.astype(jnp.int32), 0, n_phys - 1)
-    kn = k_new.reshape(B, K, 1, D)
-    vn = v_new.reshape(B, K, 1, D)
+    kn = k_new.reshape(B, 1, K, D)
+    vn = v_new.reshape(B, 1, K, D)
 
     kernel = functools.partial(_fused_paged_decode_kernel,
                                page_size=page_size, n_t_blocks=P,
                                sm_scale=D ** -0.5)
-    grid = (B, K, P)
     out_shapes = (
         jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
         jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
         jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
     )
+    page = pl.BlockSpec((1, page_size, K, D),
+                        lambda b, j, vl, wb, wo, bt: (bt[b, j], 0, 0, 0))
+    write_page = pl.BlockSpec((1, page_size, K, D),
+                              lambda b, j, vl, wb, wo, bt:
+                              (bt[b, wb[b]], 0, 0, 0))
+    per_slot = pl.BlockSpec((1, K, G, D),
+                            lambda b, j, vl, wb, wo, bt: (b, 0, 0, 0))
+    new_row = pl.BlockSpec((1, 1, K, D),
+                           lambda b, j, vl, wb, wo, bt: (b, 0, 0, 0))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, G, D),
-                             lambda b, h, j, vl, wb, wo, bt: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, 1, D),
-                             lambda b, h, j, vl, wb, wo, bt: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, 1, D),
-                             lambda b, h, j, vl, wb, wo, bt: (b, h, 0, 0)),
-                pl.BlockSpec((1, page_size, 1, D),
-                             lambda b, h, j, vl, wb, wo, bt:
-                             (bt[b, j], 0, h, 0)),
-                pl.BlockSpec((1, page_size, 1, D),
-                             lambda b, h, j, vl, wb, wo, bt:
-                             (bt[b, j], 0, h, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, G, D),
-                             lambda b, h, j, vl, wb, wo, bt: (b, h, 0, 0)),
-                pl.BlockSpec((1, page_size, 1, D),
-                             lambda b, h, j, vl, wb, wo, bt:
-                             (bt[b, wb[b]], 0, h, 0)),
-                pl.BlockSpec((1, page_size, 1, D),
-                             lambda b, h, j, vl, wb, wo, bt:
-                             (bt[b, wb[b]], 0, h, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, D), jnp.float32),
-            ],
+            grid=(B, P),
+            in_specs=[per_slot, new_row, new_row, page, page],
+            out_specs=[per_slot, write_page, write_page],
+            scratch_shapes=_paged_scratch(K, G, D),
         ),
         out_shape=out_shapes,
         # pools are donated: inputs 7/8 of (vlen, wblk, woff, bt, q, kn,

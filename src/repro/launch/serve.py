@@ -11,9 +11,13 @@ registers the selected architectures, and drives a Poisson workload.
 * ``sim`` (default) — profile-driven executors; any scale, no JAX
   execution.
 * ``real`` — every worker runs an ``EngineExecutor``: jobs execute for
-  real on reduced-config continuous-batching engines (host CPU), measured
-  service times drive the clock, and variant profiles are re-fit from the
-  measurements (reported at the end).
+  real on continuous-batching engines, measured service times drive the
+  clock, and variant profiles are re-fit from the measurements (reported
+  at the end).
+
+``--size`` sets the model the real data plane builds: ``reduced`` (the
+default; the family's tiny CPU smoke config) or ``published`` (the
+architecture's published widths, for an accelerator).
 
 ``--real-engine`` instead drives one real continuous-batching engine
 directly (no control plane) with a mixed-length stream and reports
@@ -30,6 +34,7 @@ from __future__ import annotations
 import argparse
 from typing import Optional, Sequence
 
+from repro.configs.base import ArchConfig
 from repro.configs.registry import ARCHS
 from repro.core.api import QueryPayload, QuerySpec
 from repro.sim.cluster import make_cluster
@@ -37,7 +42,13 @@ from repro.sim.workload import poisson_arrivals
 from benchmarks.common import steady_metrics  # noqa: E402
 
 
-def _real_engine_demo(arch: str, n_reqs: int, slots: int,
+def _sized(arch: str, size: str) -> ArchConfig:
+    """The config the real data plane builds for ``arch`` at ``size``."""
+    cfg = ARCHS[arch]
+    return cfg.reduced() if size == "reduced" else cfg
+
+
+def _real_engine_demo(arch: str, size: str, n_reqs: int, slots: int,
                       page_size: Optional[int] = None,
                       n_pages: Optional[int] = None,
                       chunk_threshold: Optional[int] = None,
@@ -57,7 +68,7 @@ def _real_engine_demo(arch: str, n_reqs: int, slots: int,
     from repro.models import build_model
     from repro.serving.engine import Request, ServingEngine
 
-    base_cfg = ARCHS[arch].reduced()
+    base_cfg = _sized(arch, size)
     fp_model = build_model(base_cfg)
     fp_params = fp_model.init(jax.random.PRNGKey(0))
     cfg, model, params = base_cfg, fp_model, fp_params
@@ -79,7 +90,7 @@ def _real_engine_demo(arch: str, n_reqs: int, slots: int,
             dm = build_model(dataclasses.replace(base_cfg, quantize="int8"))
             dp = quantize_params_dense(fp_params)
         else:
-            dm = build_model(ARCHS[dname].reduced())
+            dm = build_model(_sized(dname, size))
             dp = dm.init(jax.random.PRNGKey(1))
         spec_kwargs = dict(speculate=(dm, dp), spec_k=k)
     eng = ServingEngine(model, params, max_batch=slots, max_len=64,
@@ -138,7 +149,7 @@ def _real_engine_demo(arch: str, n_reqs: int, slots: int,
               f"(rate {eng.occupancy['spec_accept_rate']:.2f})")
 
 
-def _serve_wall(c, arch_names, args) -> None:
+def _serve_wall(c, archs: Sequence[ArchConfig], args) -> None:
     """Long-running wall-clock server: a seeded Poisson client submits
     payload-carrying queries on the RealClock scheduler thread, tokens
     stream back per decode segment, and SIGINT (or the duration horizon)
@@ -156,7 +167,8 @@ def _serve_wall(c, arch_names, args) -> None:
     # decode segments per request so TTFT genuinely precedes completion
     prompt_lens = (4, 13)
     max_new = 8
-    vocabs = {a: ARCHS[a].reduced().vocab for a in arch_names}
+    arch_names = [a.name for a in archs]
+    vocabs = {a.name: a.vocab for a in archs}
     handles: list = []
     streamed = {"chunks": 0, "tokens": 0}
     stop = threading.Event()
@@ -250,6 +262,12 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                          "wall: long-running server on RealClock with "
                          "threaded engine stepping and token streaming "
                          "(needs --backend real)")
+    ap.add_argument("--size", choices=["reduced", "published"],
+                    default="reduced",
+                    help="model the real data plane builds: the tiny CPU "
+                         "smoke config (reduced) or the published widths "
+                         "(published); the sim backend always plans with "
+                         "published profiles")
     ap.add_argument("--workers", type=int, default=2)
     ap.add_argument("--cpu-workers", type=int, default=1)
     ap.add_argument("--rate", type=float, default=50.0, help="queries/s")
@@ -373,7 +391,8 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                          "clock, and --real-engine bypasses the control "
                          "plane entirely)")
     if args.real_engine:
-        _real_engine_demo(args.arch, args.real_reqs, args.real_slots,
+        _real_engine_demo(args.arch, args.size, args.real_reqs,
+                          args.real_slots,
                           page_size=args.page_size, n_pages=args.n_pages,
                           chunk_threshold=args.chunk_threshold,
                           stage_slots=args.stage_slots,
@@ -400,7 +419,12 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     if args.backend == "real" and args.arch == "all":
         raise SystemExit("--backend real needs a single --arch "
                          "(each arch builds real model params)")
-    archs = None if args.arch == "all" else [ARCHS[args.arch]]
+    if args.arch == "all":
+        archs = None
+    elif args.backend == "real":
+        archs = [_sized(args.arch, args.size)]
+    else:
+        archs = [ARCHS[args.arch]]
     from repro.core.master import MasterConfig
     cfg = MasterConfig(hedge_enabled=args.hedge,
                        breaker_enabled=args.breaker)
@@ -434,12 +458,10 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                      archs=archs, autoscale=not args.no_autoscale, cfg=cfg,
                      backend=args.backend, engine_cfg=engine_cfg,
                      clock=args.clock)
-    arch_names = [a for a in (
-        [args.arch] if args.arch != "all" else list(ARCHS))]
-
     if args.clock == "wall":
-        _serve_wall(c, arch_names, args)
+        _serve_wall(c, archs, args)
         return
+    arch_names = [a.name for a in archs] if archs else list(ARCHS)
 
     import numpy as np
     rng = np.random.default_rng(0)
@@ -469,4 +491,6 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -24,6 +24,7 @@ quantized params slot into the existing param dict and survive
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
@@ -44,12 +45,17 @@ class QuantizedWeight(NamedTuple):
     scales: jax.Array  # float32, shape = w_q.shape minus contracted axes
 
 
+@functools.partial(jax.jit, static_argnames=("axes",))
 def quantize_weight(w: jax.Array, axes: Tuple[int, ...]) -> QuantizedWeight:
     """Symmetric per-output-channel int8 quantization.
 
     ``axes`` are the contraction axes of the matmul this weight feeds
     (the reduction dims); one scale is kept per remaining (output)
     channel. absmax/127 scaling with a 1.0 floor on all-zero channels.
+    Jitted so the float32 intermediates fuse away: run eagerly, each one
+    of a stacked full-width weight is a whole copy (3.2 GB for
+    phi3-mini's ``w_gate``), more than a 16 GB chip has to spare beside
+    the bf16 tree being quantized.
     """
     w = w.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(w), axis=axes, keepdims=True)

@@ -6,8 +6,8 @@ control plane — per-query variant selection, adaptive batching, the
 monitoring daemon, and two-level autoscaling — drives *live* JAX engines
 instead of the profile-driven simulation:
 
-* ``run(variant, batch, requests)`` builds (lazily) a reduced-config
-  engine for the variant, pushes the batch through the open-loop
+* ``run(variant, batch, requests)`` builds (lazily) an engine for the
+  variant, pushes the batch through the open-loop
   ``submit()``/``step()``/``drain_completions()`` core, and returns the
   measured wall-clock service time. That measured time becomes the job's
   duration on the worker's (virtual) clock, so queueing, utilization, and
@@ -71,7 +71,7 @@ from repro.serving.engine import Request, ServingEngine
 
 @dataclasses.dataclass
 class EngineExecutorConfig:
-    """Reduced-scale engine + synthetic request shape for real execution."""
+    """Engine geometry + synthetic request shape for real execution."""
     max_batch: int = 4          # engine slots (admission queues past this)
     max_len: int = 32
     decode_block: int = 4
@@ -115,7 +115,7 @@ class EngineExecutorConfig:
 class EngineExecutor:
     """Real executor: worker jobs run on per-variant ``ServingEngine``s.
 
-    ``arch_cfgs`` maps architecture name -> (reduced) ``ArchConfig``; pass
+    ``arch_cfgs`` maps architecture name -> the ``ArchConfig`` to build; pass
     a shared ``model_cache`` dict to reuse built params across executors
     (one per worker) in the same cluster.
     """
@@ -165,9 +165,15 @@ class EngineExecutor:
                 # int8 tree is derived from (not trained separately from)
                 # the arch's params, so the fp32/int8 pair differ only by
                 # per-channel weight rounding — the accuracy/latency axis
-                # selection trades over
+                # selection trades over. The fp tree is cached only when
+                # an fp engine asked for it; otherwise it is rebuilt from
+                # the same seed (same values) and dropped once quantized:
+                # one 16 GB chip cannot keep phi3-mini's bf16 tree next to
+                # its int8 tree and still seat a KV cache.
                 from repro.models.quantize import quantize_params_dense
-                _, base_params = self._model(arch)
+                fp = self._models.get(arch)
+                base_params = fp[1] if fp is not None else \
+                    build_model(cfg).init(jax.random.PRNGKey(self.cfg.seed))
                 model = build_model(dataclasses.replace(cfg,
                                                         quantize=quantize))
                 params = quantize_params_dense(base_params)

@@ -56,11 +56,12 @@ def make_cluster(n_accel: int = 1, n_cpu: int = 0,
 
     ``backend="real"``: every worker gets an
     ``repro.serving.executor.EngineExecutor`` — jobs run for real on
-    reduced-config continuous-batching engines (host CPU), measured service
-    times drive the virtual clock, and variant profiles are re-fit from
-    the measurements as they accumulate. Pass a small ``archs`` list (each
-    arch builds real model params) and optionally an
-    ``EngineExecutorConfig`` as ``engine_cfg``.
+    continuous-batching engines built from ``archs`` exactly as given
+    (pass ``cfg.reduced()`` for host-CPU sizes, the published config for
+    the chip), measured service times drive the virtual clock, and variant
+    profiles are re-fit from the measurements as they accumulate. Pass a
+    small ``archs`` list (each arch builds real model params) and
+    optionally an ``EngineExecutorConfig`` as ``engine_cfg``.
 
     ``clock="wall"`` (requires ``backend="real"``): the control plane runs
     against ``RealClock`` — callbacks fire on a scheduler thread as wall
@@ -85,7 +86,7 @@ def make_cluster(n_accel: int = 1, n_cpu: int = 0,
     if backend == "real":
         from repro.serving.executor import (EngineExecutor,
                                             EngineExecutorConfig)
-        arch_cfgs = {a.name: a.reduced() for a in use_archs}
+        arch_cfgs = {a.name: a for a in use_archs}
         ecfg = engine_cfg or EngineExecutorConfig()
         model_cache: dict = {}   # share built params across workers
 

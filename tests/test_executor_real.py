@@ -18,6 +18,9 @@ from repro.core.worker import Executor, SimExecutor
 from repro.sim.cluster import make_cluster
 
 LLAMA = ARCHS["llama3.2-1b"]
+# real-backend clusters build their engines from the configs they are
+# given: the CPU tests hand them the reduced smoke config
+SMOKE = LLAMA.reduced()
 
 # tests that build real JAX models are excluded from the fast CI job
 slow = pytest.mark.slow
@@ -42,7 +45,7 @@ def test_real_backend_serves_and_calibrates_profiles():
     real engines, and at least one variant's m/c is re-fit from measured
     service times."""
     cfg = MasterConfig(worker_autoscale=False)
-    c = make_cluster(n_accel=1, archs=[LLAMA], autoscale=False, cfg=cfg,
+    c = make_cluster(n_accel=1, archs=[SMOKE], autoscale=False, cfg=cfg,
                      backend="real")
     before = {v.name: (v.profile.m, v.profile.c)
               for v in c.store.registry.variants.values()}
@@ -51,9 +54,9 @@ def test_real_backend_serves_and_calibrates_profiles():
     # one early query (a batch-1 job), then a burst that the worker's
     # adaptive batching packs into a larger job -> two distinct batch
     # sizes observed -> refit
-    qs = [c.api.online_query(mod_arch=LLAMA.name, latency_ms=600_000)]
+    qs = [c.api.online_query(mod_arch=SMOKE.name, latency_ms=600_000)]
     c.run_until(30.0)
-    qs += [c.api.online_query(mod_arch=LLAMA.name, latency_ms=600_000)
+    qs += [c.api.online_query(mod_arch=SMOKE.name, latency_ms=600_000)
            for _ in range(7)]
     c.run_until(300.0)
     assert all(_done(q) for q in qs), \
@@ -84,9 +87,9 @@ def test_real_backend_queries_see_measured_latency():
     """Virtual-clock query latency reflects real measured service time,
     not the analytic roofline guess."""
     cfg = MasterConfig(worker_autoscale=False)
-    c = make_cluster(n_accel=1, archs=[LLAMA], autoscale=False, cfg=cfg,
+    c = make_cluster(n_accel=1, archs=[SMOKE], autoscale=False, cfg=cfg,
                      backend="real")
-    q = c.api.online_query(mod_arch=LLAMA.name, latency_ms=600_000)
+    q = c.api.online_query(mod_arch=SMOKE.name, latency_ms=600_000)
     c.run_until(60.0)
     assert _done(q)
     w = next(iter(c.master.workers.values()))
@@ -161,7 +164,7 @@ MAX_NEW = 4
 @slow
 def test_real_payload_outputs_bit_identical_to_direct_engine():
     cfg = MasterConfig(worker_autoscale=False)
-    c = make_cluster(n_accel=1, archs=[LLAMA], autoscale=False, cfg=cfg,
+    c = make_cluster(n_accel=1, archs=[SMOKE], autoscale=False, cfg=cfg,
                      backend="real")
     spec = QuerySpec.usecase(
         "text-generation", "openwebtext", min_accuracy=0.5,
@@ -180,7 +183,8 @@ def test_real_payload_outputs_bit_identical_to_direct_engine():
     ex = w.executor
     variant = c.store.registry.variants[res.variant]
     exec_eng = ex.engines[variant.name]
-    model, params = ex._model(variant.arch)
+    model, params = ex._model(
+        variant.arch, "int8" if variant.framework == "jax-int8" else "none")
     from repro.serving.engine import Request, ServingEngine
     eng = ServingEngine(model, params, max_batch=exec_eng.max_batch,
                         max_len=exec_eng.max_len,
@@ -199,12 +203,12 @@ def test_real_offline_payload_produces_outputs():
     """Offline payloads are sliced chunk by chunk into the real engine and
     their outputs accumulate on the job in input order."""
     cfg = MasterConfig(worker_autoscale=False)
-    c = make_cluster(n_accel=1, archs=[LLAMA], autoscale=False, cfg=cfg,
+    c = make_cluster(n_accel=1, archs=[SMOKE], autoscale=False, cfg=cfg,
                      backend="real")
     prompts = tuple(tuple(int(x) for x in np.arange(2 + (i % 3)) + i)
                     for i in range(6))
     h = c.api.submit(QuerySpec.arch(
-        LLAMA.name, mode="offline",
+        SMOKE.name, mode="offline",
         payload=QueryPayload.of(prompts, max_new_tokens=2)))
     res = h.result(timeout=600.0)
     assert res.ok and res.processed >= len(prompts)
@@ -233,15 +237,15 @@ def test_oversized_payload_fails_query_without_wedging_device():
     usable for subsequent queries."""
     cfg = MasterConfig(worker_autoscale=False, max_retries=1,
                        retry_delay=0.1)
-    c = make_cluster(n_accel=1, archs=[LLAMA], autoscale=False, cfg=cfg,
+    c = make_cluster(n_accel=1, archs=[SMOKE], autoscale=False, cfg=cfg,
                      backend="real")
     bad = c.api.submit(QuerySpec.arch(
-        LLAMA.name, latency_ms=600_000,
+        SMOKE.name, latency_ms=600_000,
         payload=QueryPayload.of([list(range(40))], max_new_tokens=4)))
     res = bad.result(timeout=300.0)
     assert res.failed and not res.ok
     # the device slot was not leaked: a normal query still completes
-    ok = c.api.submit(QuerySpec.arch(LLAMA.name, latency_ms=600_000))
+    ok = c.api.submit(QuerySpec.arch(SMOKE.name, latency_ms=600_000))
     assert ok.result(timeout=300.0).ok
 
 
@@ -251,9 +255,9 @@ def test_real_backend_without_payload_returns_no_outputs():
     payload-less query on the real backend must not surface their
     decoded tokens as outputs."""
     cfg = MasterConfig(worker_autoscale=False)
-    c = make_cluster(n_accel=1, archs=[LLAMA], autoscale=False, cfg=cfg,
+    c = make_cluster(n_accel=1, archs=[SMOKE], autoscale=False, cfg=cfg,
                      backend="real")
-    h = c.api.submit(QuerySpec.arch(LLAMA.name, latency_ms=600_000))
+    h = c.api.submit(QuerySpec.arch(SMOKE.name, latency_ms=600_000))
     res = h.result(timeout=300.0)
     assert res.ok and res.outputs is None
 
@@ -263,17 +267,17 @@ def test_oversized_offline_payload_fails_once_not_forever():
     """A poisoned offline chunk must fail the job and leave the worker's
     offline queue — not be retried on every monitor tick."""
     cfg = MasterConfig(worker_autoscale=False)
-    c = make_cluster(n_accel=1, archs=[LLAMA], autoscale=False, cfg=cfg,
+    c = make_cluster(n_accel=1, archs=[SMOKE], autoscale=False, cfg=cfg,
                      backend="real")
     h = c.api.submit(QuerySpec.arch(
-        LLAMA.name, mode="offline",
+        SMOKE.name, mode="offline",
         payload=QueryPayload.of([list(range(40))], max_new_tokens=4)))
     res = h.result(timeout=300.0)
     assert res.failed and h.job.failed
     w = next(iter(c.master.workers.values()))
     assert h.job not in w.offline_jobs
     # and the cluster still serves normal traffic afterwards
-    ok = c.api.submit(QuerySpec.arch(LLAMA.name, latency_ms=600_000))
+    ok = c.api.submit(QuerySpec.arch(SMOKE.name, latency_ms=600_000))
     assert ok.result(timeout=300.0).ok
 
 

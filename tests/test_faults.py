@@ -26,6 +26,7 @@ from repro.serving.runtime import ServingRuntime
 from repro.sim.cluster import make_cluster
 
 LLAMA = ARCHS["llama3.2-1b"]
+SMOKE = LLAMA.reduced()   # what the real-backend clusters serve
 slow = pytest.mark.slow
 
 
@@ -193,16 +194,16 @@ def test_breaker_pressure_and_staleness_signals():
 # wall-clock chaos: stepper death and deadline enforcement (slow)
 
 def _spec(rng, max_new=10, latency_ms=600_000):
-    vocab = LLAMA.reduced().vocab
+    vocab = SMOKE.vocab
     prompt = rng.integers(0, vocab,
                           size=int(rng.integers(4, 10))).astype(np.int32)
-    return QuerySpec.arch(LLAMA.name, latency_ms=latency_ms,
+    return QuerySpec.arch(SMOKE.name, latency_ms=latency_ms,
                           payload=QueryPayload.of([prompt],
                                                   max_new_tokens=max_new))
 
 
 def _wall_cluster(ecfg):
-    return make_cluster(n_accel=1, archs=[LLAMA], autoscale=False,
+    return make_cluster(n_accel=1, archs=[SMOKE], autoscale=False,
                         backend="real", clock="wall", engine_cfg=ecfg)
 
 
@@ -260,5 +261,36 @@ def test_deadline_enforce_surfaces_timed_out_verdict():
         h2 = rt.submit(_spec(np.random.default_rng(3)))
         res2 = h2.result(timeout=600.0)
         assert res2.ok and res2.timed_out is False
+    finally:
+        rt.shutdown(drain=True, timeout=60.0)
+
+
+def test_stepper_exception_reaches_failed_result(monkeypatch, caplog):
+    """An engine exception on the threaded stepper still fails the query
+    once its retries are spent, but its type and message now reach the
+    result, and the worker logs the traceback."""
+    from repro.serving.engine import ServingEngine
+
+    def boom(self):
+        raise RuntimeError("boom")
+
+    monkeypatch.setattr(ServingEngine, "step", boom)
+    cfg = MasterConfig(worker_autoscale=False, max_retries=1,
+                       retry_delay=0.05)
+    c = make_cluster(n_accel=1, archs=[SMOKE], autoscale=False, cfg=cfg,
+                     backend="real", clock="wall",
+                     engine_cfg=EngineExecutorConfig(max_batch=2, max_len=32,
+                                                     decode_block=4))
+    rt = ServingRuntime(c)
+    try:
+        with caplog.at_level("ERROR", logger="repro.core.worker"):
+            res = rt.submit(_spec(np.random.default_rng(4))).result(
+                timeout=600.0)
+        assert res.failed and not res.ok
+        assert res.attempts == 2
+        assert res.error == "RuntimeError: boom"
+        assert sum(ex.stepper_restarts for ex in c.executors) == 2
+        logged = [r for r in caplog.records if r.exc_info is not None]
+        assert logged and all("boom" in str(r.exc_info[1]) for r in logged)
     finally:
         rt.shutdown(drain=True, timeout=60.0)

@@ -17,6 +17,7 @@ from repro.serving.runtime import ServingRuntime, ThreadedEngineExecutor
 from repro.sim.cluster import make_cluster
 
 LLAMA = ARCHS["llama3.2-1b"]
+SMOKE = LLAMA.reduced()   # what the real-backend clusters serve
 slow = pytest.mark.slow
 
 
@@ -44,16 +45,16 @@ def test_threaded_executor_disables_engine_eviction():
 
 def _wall_cluster():
     ecfg = EngineExecutorConfig(max_batch=4, max_len=48, decode_block=4)
-    return make_cluster(n_accel=1, archs=[LLAMA], autoscale=False,
+    return make_cluster(n_accel=1, archs=[SMOKE], autoscale=False,
                         backend="real", clock="wall", engine_cfg=ecfg)
 
 
 def _spec(rng, n_prompts=1, max_new=10):
-    vocab = LLAMA.reduced().vocab
+    vocab = SMOKE.vocab
     prompts = [rng.integers(0, vocab,
                             size=int(rng.integers(4, 12))).astype(np.int32)
                for _ in range(n_prompts)]
-    return QuerySpec.arch(LLAMA.name, latency_ms=600_000,
+    return QuerySpec.arch(SMOKE.name, latency_ms=600_000,
                           payload=QueryPayload.of(prompts,
                                                   max_new_tokens=max_new))
 
@@ -98,7 +99,7 @@ def test_wall_worker_hang_mid_stream_retries_and_streams_exact():
     prefix against its per-input cursor, so the dead attempt's partial
     chunks are never duplicated."""
     ecfg = EngineExecutorConfig(max_batch=4, max_len=48, decode_block=4)
-    c = make_cluster(n_accel=2, archs=[LLAMA], autoscale=False,
+    c = make_cluster(n_accel=2, archs=[SMOKE], autoscale=False,
                      backend="real", clock="wall", engine_cfg=ecfg)
     rt = ServingRuntime(c)
     try:
@@ -138,10 +139,10 @@ def test_wall_submit_rejects_oversized_prompt():
     c = _wall_cluster()
     rt = ServingRuntime(c)
     try:
-        vocab = LLAMA.reduced().vocab
+        vocab = SMOKE.vocab
         too_long = np.arange(60, dtype=np.int32) % vocab   # > max_len 48
         h = rt.submit(QuerySpec.arch(
-            LLAMA.name, latency_ms=600_000,
+            SMOKE.name, latency_ms=600_000,
             payload=QueryPayload.of([too_long], max_new_tokens=4)))
         res = h.result(timeout=120.0)
         assert res.failed and not res.ok

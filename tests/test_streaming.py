@@ -158,14 +158,14 @@ def test_streaming_through_control_plane_virtual_clock():
     from repro.serving.executor import EngineExecutorConfig
     from repro.sim.cluster import make_cluster
 
-    arch = ARCHS["llama3.2-1b"]
+    arch = ARCHS["llama3.2-1b"].reduced()
     ecfg = EngineExecutorConfig(max_batch=4, max_len=48, decode_block=4,
                                 stream=True)
     c = make_cluster(n_accel=1, archs=[arch], autoscale=False,
                      backend="real", engine_cfg=ecfg)
     rng = np.random.default_rng(3)
-    prompts = [rng.integers(0, arch.reduced().vocab, size=6),
-               rng.integers(0, arch.reduced().vocab, size=9)]
+    prompts = [rng.integers(0, arch.vocab, size=6),
+               rng.integers(0, arch.vocab, size=9)]
     h = c.api.submit(QuerySpec.arch(
         arch.name, latency_ms=600_000,
         payload=QueryPayload.of(prompts, max_new_tokens=10)))
