@@ -16,6 +16,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from repro.configs.base import ArchConfig
 from repro.core import profiler as prof
+from repro.core import spans
 from repro.core.abstraction import ModelArchInfo, Variant
 from repro.core.api import (ArchTarget, QueryHandle, QuerySpec,
                             UseCaseTarget, VariantTarget, _spec_from_kwargs)
@@ -319,8 +320,9 @@ class Master:
         # straight into the handle (hedged duplicates are created without
         # a sink, so only the primary copy ever streams)
         q.on_tokens = handle._push_tokens
-        sel = self._select(spec, batch=spec.n_inputs, record=True)
-        self._dispatch(q, sel, retries=0)
+        with spans.span("master.dispatch", q.qid):
+            sel = self._select(spec, batch=spec.n_inputs, record=True)
+            self._dispatch(q, sel, retries=0)
         return handle
 
     def _retry_delay_for(self, retries: int) -> float:

@@ -26,10 +26,12 @@ import dataclasses
 import functools
 import logging
 import threading
+import time
 from collections import deque
 from typing import (Any, Callable, Deque, Dict, List, Optional, Protocol,
                     Tuple, runtime_checkable)
 
+from repro.core import spans
 from repro.core.metadata import InstanceState, MetadataStore
 from repro.core.repository import ModelRepository
 from repro.sim import hardware as HW
@@ -108,6 +110,9 @@ class Query:
     # "Type: message" of the latest executor exception that failed one of
     # this query's attempts ("" if none did); survives the retry path
     error: str = ""
+    # perf_counter_ns() at the latest Worker.enqueue, stamped only while
+    # the span recorder is on (0 otherwise): the start of ``worker.queue``
+    enqueued_ns: int = 0
 
     @property
     def latency(self) -> float:
@@ -158,6 +163,7 @@ class ExecRequest:
     # streaming sink: (input_idx, new_tokens, t_wall) per harvested
     # segment, in emission order; only streaming executors call it
     on_tokens: Optional[Callable[[int, List[int], float], None]] = None
+    qid: int = -1                   # the query's ``Query.qid`` (for spans)
 
 
 @runtime_checkable
@@ -388,6 +394,8 @@ class Worker:
                 q.done_cb(q)
             return
         q.worker = self.name
+        if spans.on():
+            q.enqueued_ns = time.perf_counter_ns()
         li.pending.append(q)
         if li.running:
             self._try_dispatch(vname)
@@ -441,9 +449,10 @@ class Worker:
                 max_new_tokens=q.payload.max_new_tokens,
                 on_outputs=lambda outs, qq=q: setattr(qq, "outputs", outs),
                 slo=q.slo, on_report=report,
-                on_tokens=tokens if q.on_tokens is not None else None)
+                on_tokens=tokens if q.on_tokens is not None else None,
+                qid=q.qid)
         return ExecRequest(n_inputs=q.n_inputs, slo=q.slo,
-                           on_report=report)
+                           on_report=report, qid=q.qid)
 
     @_locked
     def _try_dispatch(self, vname: str) -> None:
@@ -480,6 +489,11 @@ class Worker:
             dev.waiting.append(job)
 
     def _start(self, dev: _Device, job: _Job) -> None:
+        if spans.on():
+            t = time.perf_counter_ns()
+            for q in job.queries:
+                if q.enqueued_ns:
+                    spans.interval("worker.queue", q.enqueued_ns, t, q.qid)
         run_async = getattr(self.executor, "run_async", None)
         if run_async is not None:
             self._start_async(dev, job, run_async)

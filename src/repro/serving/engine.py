@@ -192,6 +192,7 @@ import numpy as np
 from jax import lax
 
 from repro.configs.base import ArchConfig
+from repro.core import spans
 from repro.models import kvcache as KV
 from repro.models.model import Model, build_model
 
@@ -223,6 +224,7 @@ class Request:
     # set by ``ServingEngine.cancel``: the request was cut short (deadline
     # enforcement) and ``tokens`` holds only what was generated by then
     cancelled: bool = False
+    qid: int = -1            # the control plane's query id (for spans)
 
 
 @dataclasses.dataclass
@@ -2033,6 +2035,12 @@ class ServingEngine:
         if n > r.streamed:
             if r.first_token < 0.0:
                 r.first_token = now
+                if spans.on():
+                    spans.interval("engine.queue", spans.ns(r.arrival),
+                                   spans.ns(r.admitted), r.qid)
+                    spans.interval("engine.first_chunk",
+                                   spans.ns(r.admitted), spans.ns(now),
+                                   r.qid)
             self._partial.append((r, [int(x) for x in done[r.streamed:n]],
                                   now))
             r.streamed = n
@@ -2213,7 +2221,8 @@ class ServingEngine:
         draft-then-verify round (``_try_spec_step``); segments remain the
         fallback for prefix feeding, end-of-request tails, and page
         pressure, so every request still completes."""
-        self._admit_pending()
+        with spans.span("engine.admit"):
+            self._admit_pending()
         live = sum(r is not None for r in self._slot_req)
         if live == 0:
             return 0
@@ -2284,23 +2293,36 @@ class ServingEngine:
          self._pbuf, out, comp_slot, comp_step, comp_adm, n_comp,
          busy_steps, n_steps) = decode(*args)
         self.stats["decode_dispatches"] += 1
-        out_np = np.asarray(out)                     # the one host sync
-        comp_slot = np.asarray(comp_slot)
-        comp_step = np.asarray(comp_step)
-        comp_adm = np.asarray(comp_adm)
-        n_comp = int(n_comp)
-        n_steps = int(n_steps)
+        with spans.span("engine.sync"):
+            out_np = np.asarray(out)                 # the one host sync
+            comp_slot = np.asarray(comp_slot)
+            comp_step = np.asarray(comp_step)
+            comp_adm = np.asarray(comp_adm)
+            n_comp = int(n_comp)
+            n_steps = int(n_steps)
+            busy_steps = int(busy_steps)
         if n_steps:
             # EWMA per-step wall time (all slots advance in lockstep):
             # feeds the slack policy's remaining-service estimate
             per = (time.perf_counter() - t_seg) / n_steps
             self._step_est = per if self._step_est == 0.0 \
                 else 0.8 * self._step_est + 0.2 * per
+        with spans.span("engine.harvest"):
+            self._harvest(out_np, comp_slot, comp_step, comp_adm, n_comp,
+                          n_steps, busy_steps)
+        return n_steps
+
+    def _harvest(self, out_np: np.ndarray, comp_slot: np.ndarray,
+                 comp_step: np.ndarray, comp_adm: np.ndarray, n_comp: int,
+                 n_steps: int, busy_steps: int) -> None:
+        """Book a finished decode segment on the host: counters, then the
+        completion log (retire, in-segment seats), emitted tokens, the
+        streaming flush, and prefix-page registration."""
         self._slot_pos = np.asarray(self._pos).astype(np.int64)
         self.stats["decode_steps"] += n_steps
-        self.stats["busy_slot_steps"] += int(busy_steps)
+        self.stats["busy_slot_steps"] += busy_steps
         self.stats["bubble_slot_steps"] += \
-            n_steps * self.max_batch - int(busy_steps)
+            n_steps * self.max_batch - busy_steps
         now = time.perf_counter()
         # completion log, in segment order: each record closes the slot's
         # current occupant over out[slot, consumed:step+1]; an "admitted"
@@ -2350,7 +2372,6 @@ class ServingEngine:
                 self._retire_slot(s, r, now)
                 self._free.append(s)
         self._register_prefix_pages()
-        return n_steps
 
     def _register_prefix_pages(self) -> None:
         """Prefix registration: index every prompt page the last dispatch

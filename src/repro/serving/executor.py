@@ -131,17 +131,18 @@ class EngineExecutor:
         self.observations: Dict[str, Dict[int, Deque[float]]] = {}
         self.refits: Dict[str, int] = {}                 # refit count
         self.evictions = 0                               # LRU engine drops
-        # per-run occupancy records (the executor's decision log): how
-        # full the fused segments ran, and how many requests in-segment
-        # admission packed into them — the data-plane side of the control
-        # plane's decision accounting. Bounded like `observations` so a
-        # long-running cluster's memory stays flat.
+        # per-run occupancy records of the synchronous ``run`` path (the
+        # executor's decision log): how full the fused segments ran, and
+        # how many requests in-segment admission packed into them.
+        # Bounded like `observations` so a long-running cluster's memory
+        # stays flat.
         self.occupancy_log: Deque[Dict[str, Any]] = \
             deque(maxlen=max(cfg.obs_window * 8, 256))
-        # monotone total of pressure events (preemptions + stalls) ever
-        # logged. The master's circuit breaker diffs this across sweeps;
-        # the bounded deque above is no good for that (entries fall off
-        # the left once it wraps, so positional bookkeeping drifts).
+        # monotone total of the engines' pressure events (preemptions +
+        # stalls), on both the synchronous and the threaded path. The
+        # master's circuit breaker diffs this across sweeps; the bounded
+        # deque above is no good for that (entries fall off the left once
+        # it wraps, so positional bookkeeping drifts).
         self.pressure_events_total: float = 0.0
         self._models = model_cache if model_cache is not None else {}
         self._rid = itertools.count()
@@ -281,14 +282,14 @@ class EngineExecutor:
                     rid=next(self._rid),
                     prompt=np.asarray(p, np.int32),
                     max_new_tokens=max(er.max_new_tokens, 1),
-                    arrival=t0, slo=er.slo))
+                    arrival=t0, slo=er.slo, qid=er.qid))
         else:
             for _ in range(max(er.n_inputs, 1)):
                 ers.append(Request(
                     rid=next(self._rid),
                     prompt=self._synthetic_prompt(vocab),
                     max_new_tokens=self.cfg.max_new, arrival=t0,
-                    slo=er.slo))
+                    slo=er.slo, qid=er.qid))
         return ers
 
     def _pump_stream(self, eng: ServingEngine,
@@ -313,13 +314,6 @@ class EngineExecutor:
                           eng: ServingEngine) -> None:
         # decision-log entry: per-run occupancy of the fused segments
         d = {k: eng.stats.get(k, 0) - occ0[k] for k in occ0}
-        self._record_occupancy_delta(variant, batch, dt, d)
-
-    def _record_occupancy_delta(self, variant: Variant, batch: int,
-                                dt: float, d: Dict[str, float]) -> None:
-        """Append one decision-log entry from an already-computed stats
-        delta (the threaded runtime accumulates per-job fractional deltas
-        across co-batched segments instead of diffing raw counters)."""
         total = d["busy_slot_steps"] + d["bubble_slot_steps"]
         segs = d["decode_dispatches"]
         self.occupancy_log.append({

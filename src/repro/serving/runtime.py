@@ -45,6 +45,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro.core import spans
 from repro.core.worker import ExecRequest
 from repro.serving.engine import Request
 from repro.serving.executor import EngineExecutor, EngineExecutorConfig
@@ -53,22 +54,22 @@ from repro.serving.executor import EngineExecutor, EngineExecutorConfig
 class _WallJob:
     """One ``run_async`` job in flight on the stepper thread."""
     __slots__ = ("variant", "batch", "eng", "groups", "on_done", "t0",
-                 "occ", "outstanding", "synthetic")
+                 "outstanding", "synthetic")
 
-    def __init__(self, variant, batch, eng, groups, on_done, t0, occ_keys):
+    def __init__(self, variant, batch, eng, groups, on_done, t0):
         self.variant = variant
         self.batch = batch
         self.eng = eng
         self.groups: List[Tuple[ExecRequest, List[Request]]] = groups
         self.on_done = on_done
         self.t0 = t0
-        # per-job occupancy accumulator: each engine step's stats delta
-        # is split across the jobs co-batched on that engine by live-slot
-        # share, so a job is billed only for its fraction of shared
-        # segments (fractional, unlike the raw engine counters)
-        self.occ: Dict[str, float] = {k: 0.0 for k in occ_keys}
         self.outstanding = sum(len(ers) for _, ers in groups)
         self.synthetic = not any(er.prompts for er, _ in groups)
+
+
+def _pressure(eng) -> int:
+    return eng.stats.get("preemptions", 0) + \
+        eng.stats.get("pressure_stalls", 0)
 
 
 class ThreadedEngineExecutor(EngineExecutor):
@@ -114,7 +115,9 @@ class ThreadedEngineExecutor(EngineExecutor):
         (e.g. a prompt exceeding the engine's max_len)."""
         if self._stopping:
             raise RuntimeError("executor is shutting down")
-        self._queue.put((variant, batch, requests, on_done))
+        # the put's time starts each query's ``executor.handoff`` span
+        t_put = time.perf_counter_ns() if spans.on() else 0
+        self._queue.put((variant, batch, requests, on_done, t_put))
         if self._thread is None or not self._thread.is_alive():
             self._thread = threading.Thread(
                 target=self._step_loop, name="engine-stepper", daemon=True)
@@ -131,7 +134,7 @@ class ThreadedEngineExecutor(EngineExecutor):
 
     # ------------------------------------------------------------------
     def _admit_job(self, item: tuple) -> None:
-        variant, batch, requests, on_done = item
+        variant, batch, requests, on_done, t_put = item
         try:
             if self._faults is not None:
                 self._faults.check("dispatch")
@@ -147,7 +150,14 @@ class ThreadedEngineExecutor(EngineExecutor):
                 real_lens = [len(p) for er in requests for p in er.prompts]
                 if real_lens:
                     eng.warmup(prompt_lens=real_lens)
-                t0 = time.perf_counter()
+                t0_ns = time.perf_counter_ns()
+                t0 = t0_ns * 1e-9
+                if t_put:
+                    # from the put to the requests' arrival in the engine
+                    # (any bucket warm-up above included)
+                    for er in requests:
+                        spans.interval("executor.handoff", t_put, t0_ns,
+                                       er.qid)
                 groups: List[Tuple[ExecRequest, List[Request]]] = []
                 for er in requests:
                     groups.append((er, self._make_requests(er, vocab, t0)))
@@ -157,7 +167,7 @@ class ThreadedEngineExecutor(EngineExecutor):
                     for r in ers:
                         eng._validate(r)
                 job = _WallJob(variant, int(batch), eng, groups, on_done,
-                               t0, self._OCC_KEYS)
+                               t0)
                 for er, ers in groups:
                     for i, r in enumerate(ers):
                         eng.submit(r)
@@ -177,11 +187,6 @@ class ThreadedEngineExecutor(EngineExecutor):
             return
         dt = time.perf_counter() - job.t0
         self._active.remove(job)
-        # co-batched jobs overlap on one engine; `job.occ` holds this
-        # job's live-slot share of every shared segment (accumulated in
-        # `_split_occupancy`), so the log entry bills each job its own
-        # fraction instead of double-counting shared work
-        self._record_occupancy_delta(job.variant, job.batch, dt, job.occ)
         for er, ers in job.groups:
             self._deliver(er, ers)
         if job.synthetic:
@@ -226,71 +231,54 @@ class ThreadedEngineExecutor(EngineExecutor):
         while True:
             # pull new work: block briefly only when fully idle, so an
             # idle executor doesn't spin and a busy one doesn't stall
-            block = not self._active
             try:
-                item = self._queue.get(timeout=0.05) if block \
-                    else self._queue.get_nowait()
-                while item is not None:
-                    self._admit_job(item)
+                if self._active:
                     item = self._queue.get_nowait()
+                else:
+                    with spans.span("stepper.wait"):
+                        item = self._queue.get(timeout=0.05)
             except queue.Empty:
-                pass
-            if not self._active:
+                item = None
+            if item is None and not self._active:
                 if self._stopping and self._queue.empty():
                     return
                 continue
-            if self._faults is not None:
-                self._faults.check("stepper")    # → _step_loop restarts
-                if self._faults.fire("slow_step"):
-                    time.sleep(self._faults.slow_step_s)
-            engines = []
+            with spans.span("stepper.iter"):
+                self._iterate(item)
+
+    def _iterate(self, item: Optional[tuple]) -> None:
+        """Admit the queued jobs (``item`` first), then step every engine
+        that has live work once."""
+        while item is not None:
+            self._admit_job(item)
+            try:
+                item = self._queue.get_nowait()
+            except queue.Empty:
+                item = None
+        if not self._active:
+            return
+        if self._faults is not None:
+            self._faults.check("stepper")    # → _step_loop restarts
+            if self._faults.fire("slow_step"):
+                time.sleep(self._faults.slow_step_s)
+        engines = []
+        with self._lock:
+            for job in self._active:
+                if job.eng not in engines:
+                    engines.append(job.eng)
+        for eng in engines:
             with self._lock:
-                for job in self._active:
-                    if job.eng not in engines:
-                        engines.append(job.eng)
-            for eng in engines:
-                with self._lock:
-                    if self.cfg.deadline_enforce:
-                        eng.cancel_overdue()
-                    if eng.busy:
-                        occ0 = {k: eng.stats.get(k, 0)
-                                for k in self._OCC_KEYS}
-                        seated0 = {id(r) for r in eng._slot_req
-                                   if r is not None}
-                        eng.step()
+                if self.cfg.deadline_enforce:
+                    eng.cancel_overdue()
+                if eng.busy:
+                    p0 = _pressure(eng)
+                    eng.step()
+                    # the breaker's signal: this step's raw pressure events
+                    self.pressure_events_total += _pressure(eng) - p0
+                    with spans.span("stepper.pump"):
                         self._pump_stream(eng, self._sinks)
-                        self._split_occupancy(eng, occ0, seated0)
-                    for r in eng.drain_completions():
-                        self._finish_request(r)
-
-    def _split_occupancy(self, eng, occ0: Dict[str, int],
-                         seated0: set) -> None:
-        """Attribute one step's stats delta to the jobs co-batched on
-        ``eng``, each weighted by its live-slot share of the segment.
-
-        A request counts toward its job's share if it was seated before
-        the step or is seated after it (so work done by requests that
-        retired mid-step, and by ones the step itself admitted, is still
-        billed to their jobs). When no seated request maps to a job —
-        e.g. a segment that only shuffled the admission queue — the delta
-        is split evenly across the engine's jobs so counters never leak."""
-        seated = seated0 | {id(r) for r in eng._slot_req if r is not None}
-        counts: Dict[_WallJob, int] = {}
-        for rid in seated:
-            job = self._req_job.get(rid)
-            if job is not None:
-                counts[job] = counts.get(job, 0) + 1
-        if not counts:
-            counts = {j: 1 for j in self._active if j.eng is eng}
-            if not counts:
-                return
-        total = sum(counts.values())
-        delta = {k: eng.stats.get(k, 0) - occ0[k] for k in occ0}
-        for job, n in counts.items():
-            share = n / total
-            for k, v in delta.items():
-                if v:
-                    job.occ[k] += v * share
+                for r in eng.drain_completions():
+                    self._finish_request(r)
 
 
 class ServingRuntime:
