@@ -3,9 +3,10 @@
 Caches are plain pytrees (dicts of arrays) so they cross pjit/shard_map
 boundaries and checkpoint naturally. Attention caches come in two layouts:
 
-* contiguous — (L, B, S_max, K, D), layer-major so the per-layer scan can
-  consume them as scan xs and emit updated slices as ys. Every batch slot
-  owns a private ``S_max`` run of positions.
+* contiguous — (L, B, S_max, K, D), layer-major: a per-layer scan either
+  consumes them as scan xs and emits updated slices as ys, or (dense
+  decode) carries the whole stack and writes each layer's new token in
+  place. Every batch slot owns a private ``S_max`` run of positions.
 * paged — a shared page pool (L, n_pages, page_size, K, D) plus a per-slot
   block table (B, S_max // page_size) of page indices. Logical position
   ``p`` of slot ``b`` lives at ``pool[bt[b, p // page_size], p % page_size]``.
@@ -73,6 +74,38 @@ def update_layer_cache(k_cache: jax.Array, v_cache: jax.Array,
         lambda c, n, p: lax.dynamic_update_slice_in_dim(c, n, p, axis=0))
     k_cache = write(k_cache, k_new.astype(k_cache.dtype), pos)
     v_cache = write(v_cache, v_new.astype(v_cache.dtype), pos)
+    return k_cache, v_cache
+
+
+def update_stacked_layer_cache(k_cache: jax.Array, v_cache: jax.Array,
+                               k_new: jax.Array, v_new: jax.Array,
+                               layer: Any,
+                               pos: Any) -> Tuple[jax.Array, jax.Array]:
+    """Write one token's (B, 1, K, D) k/v into layer ``layer`` of a stacked
+    (L, B, S_max, K, D) cache, touching only the written rows.
+
+    The layer-indexed twin of :func:`update_layer_cache` for a decode scan
+    that carries the whole stacked cache: a scalar ``pos`` is one
+    ``dynamic_update_slice`` at ``(layer, 0, pos)``, a (B,) ``pos`` a
+    per-slot scatter. ``pos`` is clipped to ``[0, S_max - 1]`` so a slot at
+    (or past) the end writes where the clamping per-layer write did.
+    """
+    S = k_cache.shape[2]
+    pos = jnp.clip(pos, 0, S - 1)
+    if jnp.ndim(pos) == 0:
+        start = (layer, 0, pos, 0, 0)
+        k_cache = lax.dynamic_update_slice(
+            k_cache, k_new[None].astype(k_cache.dtype), start)
+        v_cache = lax.dynamic_update_slice(
+            v_cache, v_new[None].astype(v_cache.dtype), start)
+        return k_cache, v_cache
+    rows = jnp.arange(k_cache.shape[1])
+    k_cache = k_cache.at[layer, rows, pos].set(
+        k_new[:, 0].astype(k_cache.dtype), unique_indices=True,
+        mode="promise_in_bounds")
+    v_cache = v_cache.at[layer, rows, pos].set(
+        v_new[:, 0].astype(v_cache.dtype), unique_indices=True,
+        mode="promise_in_bounds")
     return k_cache, v_cache
 
 

@@ -206,6 +206,30 @@ def decode_positions(pos, batch: int) -> jax.Array:
     return jnp.reshape(pos, (batch, 1))
 
 
+def _decode_qkv(x, blk, pos, cfg: ArchConfig):
+    """Norm, q/k/v projections and RoPE of one decode step. x: (B,1,d)."""
+    h = L.rmsnorm(x, blk["ln1"])
+    q, k, v = L.attn_qkv(h, blk["attn"], qimpl=_qimpl(cfg))
+    positions = decode_positions(pos, x.shape[0])
+    q = L.apply_rope(q, positions, cfg.rope_theta)
+    k = L.apply_rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def _decode_attend(q, kc, vc, pos, cfg: ArchConfig):
+    """Attention of the step's queries over a contiguous (B,Smax,K,D) slot
+    row, masked to each slot's positions ``<= pos``."""
+    return L.attention_core(q, kc, vc, causal=False, kv_valid_len=pos + 1,
+                            impl=cfg.attention_impl)
+
+
+def _decode_out(x, o, blk, cfg: ArchConfig):
+    """Output projection and MLP of one decode step, both residual."""
+    qi = _qimpl(cfg)
+    x = x + L.attn_out(o, blk["attn"], qimpl=qi)
+    return x + L.swiglu(L.rmsnorm(x, blk["ln2"]), blk["mlp"], qimpl=qi)
+
+
 def _decode_block(x, blk, kc, vc, pos, cfg: ArchConfig, bt=None):
     """One decode step through one block. x: (B,1,d).
 
@@ -214,22 +238,14 @@ def _decode_block(x, blk, kc, vc, pos, cfg: ArchConfig, bt=None):
     (B, P) block table they are shared (n_pages, page_size, K, D) pools and
     the write/attend both route through the slot's block table.
     """
-    qi = _qimpl(cfg)
-    h = L.rmsnorm(x, blk["ln1"])
-    q, k, v = L.attn_qkv(h, blk["attn"], qimpl=qi)
-    positions = decode_positions(pos, x.shape[0])
-    q = L.apply_rope(q, positions, cfg.rope_theta)
-    k = L.apply_rope(k, positions, cfg.rope_theta)
+    q, k, v = _decode_qkv(x, blk, pos, cfg)
     if bt is None:
         kc, vc = KV.update_layer_cache(kc, vc, k, v, pos)
-        o = L.attention_core(q, kc, vc, causal=False, kv_valid_len=pos + 1,
-                             impl=cfg.attention_impl)
+        o = _decode_attend(q, kc, vc, pos, cfg)
     else:
         o, kc, vc = L.paged_update_attend(q, k, v, kc, vc, bt, pos,
                                           impl=cfg.attention_impl)
-    x = x + L.attn_out(o, blk["attn"], qimpl=qi)
-    x = x + L.swiglu(L.rmsnorm(x, blk["ln2"]), blk["mlp"], qimpl=qi)
-    return x, kc, vc
+    return _decode_out(x, o, blk, cfg), kc, vc
 
 
 def decode_dense(cfg: ArchConfig, params: Params, cache, token: jax.Array,
@@ -237,17 +253,38 @@ def decode_dense(cfg: ArchConfig, params: Params, cache, token: jax.Array,
     """serve_step: one new token against the cache. token: (B,1) int32.
 
     ``cache`` may carry a ``"bt"`` block table, in which case its k/v
-    leaves are shared page pools (see ``repro.models.kvcache``)."""
+    leaves are shared page pools (see ``repro.models.kvcache``), scanned
+    layer by layer as xs/ys. A contiguous (L,B,Smax,K,D) cache instead
+    rides in the scan carry: layer ``l`` writes its token's rows in place
+    and attends over its slice read back after the write, so no step
+    copies a layer slice, or the stack, whole."""
     dtype = jnp.dtype(cfg.dtype)
     bt = cache.get("bt")
     x = L.embed_tokens(token, params["embed"], dtype)
 
-    def body(carry, xs):
-        blk, kc, vc = xs
-        out, kc, vc = _decode_block(carry, blk, kc, vc, pos, cfg, bt=bt)
-        return out, (kc, vc)
+    if bt is None:
+        def body(carry, xs):
+            h, ks, vs = carry
+            blk, layer = xs
+            q, k, v = _decode_qkv(h, blk, pos, cfg)
+            ks, vs = KV.update_stacked_layer_cache(ks, vs, k, v, layer, pos)
+            o = _decode_attend(
+                q, lax.dynamic_index_in_dim(ks, layer, keepdims=False),
+                lax.dynamic_index_in_dim(vs, layer, keepdims=False), pos, cfg)
+            return (_decode_out(h, o, blk, cfg), ks, vs), None
 
-    x, (ks, vs) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+        n_layers = cache["k"].shape[0]
+        (x, ks, vs), _ = lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
+    else:
+        def body(carry, xs):
+            blk, kc, vc = xs
+            out, kc, vc = _decode_block(carry, blk, kc, vc, pos, cfg, bt=bt)
+            return out, (kc, vc)
+
+        x, (ks, vs) = lax.scan(body, x,
+                               (params["layers"], cache["k"], cache["v"]))
     x = L.rmsnorm(x, params["ln_f"])
     logits = L.lm_logits(x, params["head"])
     out_cache = {"k": ks, "v": vs}
