@@ -321,13 +321,9 @@ class MasterAutoscaler:
                     break   # at most one per tick (paper: not reckless)
 
     def _latency_spike(self, worker: str) -> bool:
-        for inst in self.store.worker_instances(worker):
-            v = self.store.variant(inst.variant)
-            expected = v.profile.latency(v.batch_opt)
-            if inst.avg_latency > self.cfg.latency_spike_factor * expected \
-                    and inst.avg_latency > 0:
-                return True
-        return False
+        return any(self.store.slower_than_profile(
+            inst, self.cfg.latency_spike_factor)
+            for inst in self.store.worker_instances(worker))
 
     def _start(self, kind: str) -> None:
         self.pending_starts += 1

@@ -23,7 +23,10 @@ class InstanceState:
     worker: str
     replicas: int = 1
     qps: float = 0.0               # batch-weighted request rate (EWMA)
-    avg_latency: float = 0.0       # seconds (EWMA)
+    # measured service seconds of one step of the profile's t(b), queue
+    # excluded (the executor's estimate at the last monitor tick; 0 =
+    # none yet)
+    step_latency: float = 0.0
     running: bool = True
     loading: bool = False
     last_used: float = 0.0
@@ -117,11 +120,21 @@ class MetadataStore:
 
     # ------------------------------------------------------------------
     # overload predicate (paper §5: QPS and latency exceed profiled values)
+    def slower_than_profile(self, inst: InstanceState,
+                            factor: float) -> bool:
+        """The instance serves a step more than ``factor`` times slower
+        than its profile says: the measured service time per step (queue
+        excluded, ``Worker.monitor_tick``) against the profiled t(b) of
+        one step at the variant's batch. Selection's overload test and the
+        master autoscaler's latency-spike test both ask this."""
+        v = self.variant(inst.variant)
+        return inst.step_latency > factor * v.profile.latency(v.batch_opt)
+
     def is_overloaded(self, inst: InstanceState) -> bool:
         v = self.variant(inst.variant)
         qps_cap = v.profile.peak_qps * inst.replicas
         return (inst.qps >= 0.95 * qps_cap
-                or inst.avg_latency > 1.5 * v.profile.latency(v.batch_opt))
+                or self.slower_than_profile(inst, 1.5))
 
     # ------------------------------------------------------------------
     # snapshot / recovery (paper §7)

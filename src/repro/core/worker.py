@@ -184,6 +184,12 @@ class Executor(Protocol):
             requests: Optional[List[ExecRequest]] = None) -> float:
         ...
 
+    def step_latency(self, variant) -> float:
+        """Measured service seconds of one step of the variant's profiled
+        t(b), queue excluded (0.0 before the first): what the overload
+        test sets against the profile."""
+        ...
+
     # Executors may additionally expose
     #   run_async(variant, batch, requests, on_done)
     # returning immediately; ``on_done(duration, error)`` fires later from
@@ -199,12 +205,20 @@ class SimExecutor:
 
     def __init__(self, service_time_fn: Optional[Callable] = None):
         self.service_time_fn = service_time_fn
+        self._last: Dict[str, float] = {}   # variant -> last service time
 
     def run(self, variant, batch: int,
             requests: Optional[List[ExecRequest]] = None) -> float:
-        if self.service_time_fn is not None:
-            return self.service_time_fn(variant, batch)
-        return variant.profile.latency(batch)
+        t = self.service_time_fn(variant, batch) \
+            if self.service_time_fn is not None \
+            else variant.profile.latency(batch)
+        self._last[variant.name] = t
+        return t
+
+    def step_latency(self, variant) -> float:
+        """The variant's latest service time: a profile-driven job is one
+        step of t(b)."""
+        return self._last.get(variant.name, 0.0)
 
 
 @dataclasses.dataclass
@@ -269,8 +283,6 @@ class _LocalInstance:
         self.pending: Deque[Query] = deque()
         # stats since last monitor tick
         self.completed_inputs = 0.0
-        self.lat_sum = 0.0
-        self.lat_n = 0
         self.running = False
 
 
@@ -617,8 +629,6 @@ class Worker:
                     q.violated = True
                     self.recent_violations += 1
                 li.completed_inputs += q.n_inputs
-                li.lat_sum += q.latency
-                li.lat_n += 1
                 self.metrics.append(q)
                 if q.done_cb:
                     q.done_cb(q)
@@ -722,12 +732,14 @@ class Worker:
                 continue
             qps = li.completed_inputs / window
             st.qps = 0.5 * st.qps + 0.5 * qps
-            if li.lat_n:
-                st.avg_latency = li.lat_sum / li.lat_n
+            # the straggler factor scales a step as it scales a job
+            # (``_service_time``)
+            step = self.executor.step_latency(li.variant) * self.slowdown
+            if step:
+                st.step_latency = step
             st.replicas = li.replicas
             st.running = li.running
             li.completed_inputs = 0.0
-            li.lat_sum, li.lat_n = 0.0, 0
         self.recent_violations = 0
         self._pump_offline()   # periodic re-probe for slack
 

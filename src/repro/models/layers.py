@@ -12,6 +12,7 @@ memory term by G).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -280,6 +281,14 @@ def decode_attention_splitk(q, kc, vc, valid_len, ctx) -> jax.Array:
     return fn(q, kc, vc, jnp.asarray(valid_len, jnp.int32))
 
 
+# The dense XLA attention holds float32 (B, K, G, S, T) scores and their
+# softmax at once. Past this many bytes of scores (8 rows of 1,024 with 32
+# heads) a causal self-attention streams them in blocks instead: 8 rows of
+# a 2,048 prefill bucket would hold 4 GiB, which does not fit beside a 9 GB
+# model on a 16 GB chip.
+DENSE_SCORES_MAX_BYTES = 1 << 30
+
+
 def attention_core(
     q: jax.Array,                 # (B, S, K, G, D)
     k: jax.Array,                 # (B, T, K, D)
@@ -315,7 +324,12 @@ def attention_core(
             q, k, v, causal=causal, q_offset=q_offset,
             kv_valid_len=kv_valid_len,
             interpret=impl == "pallas_interpret")
-    if impl == "xla_chunked" and kv_valid_len is None \
+    # Causal self-attention whose dense float32 scores would pass
+    # DENSE_SCORES_MAX_BYTES takes the chunked path too, whatever the impl
+    # string.
+    if impl in ("xla", "xla_chunked") and kv_valid_len is None \
+            and (impl == "xla_chunked" or causal and 4 * k.shape[1]
+                 * math.prod(q.shape[:-1]) > DENSE_SCORES_MAX_BYTES) \
             and q.shape[1] > 256 and q.shape[1] % 256 == 0 \
             and k.shape[1] % 512 == 0:
         return _attention_chunked(q, k, v, causal=causal, q_offset=q_offset,

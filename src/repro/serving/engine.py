@@ -711,6 +711,9 @@ class ServingEngine:
         self.stats: Dict[str, int] = {
             "prefill_traces": 0, "decode_traces": 0, "chunk_traces": 0,
             "prefill_dispatches": 0, "decode_dispatches": 0,
+            # prompt tokens admitted by prefill, and the token rows its
+            # dispatches computed (rows x bucket, padding included)
+            "prefill_prompt_tokens": 0, "prefill_computed_tokens": 0,
             "decode_steps": 0, "tokens_generated": 0, "admitted": 0,
             "chunk_admits": 0, "peak_concurrency": 0,
             "staged": 0, "inseg_admissions": 0,
@@ -950,6 +953,12 @@ class ServingEngine:
         # EWMA of per-decode-step wall time: the slack policy's estimate
         # of a request's remaining service time (positions left x this)
         self._step_est = 0.0
+
+    @property
+    def step_seconds(self) -> float:
+        """Wall seconds of one decode step, dispatch to host sync (the
+        EWMA the slack policy reads); 0.0 before the first step."""
+        return self._step_est
 
     def _pool_shape(self, dims: Tuple[int, ...], bax: int, sax: int):
         """Contiguous leaf shape -> shared-pool shape: drop the batch axis,
@@ -1421,6 +1430,8 @@ class ServingEngine:
         (self._cache, self._tok, self._pos, self._rem, self._plen,
          firsts) = fn(*args)
         self.stats["prefill_dispatches"] += 1
+        self.stats["prefill_prompt_tokens"] += sum(len(r.prompt) for r in rs)
+        self.stats["prefill_computed_tokens"] += nb * bucket
         self.stats["admitted"] += m
         for r, s in zip(rs, slots):
             self._slot_prefix[s] = np.asarray(r.prompt, np.int32)

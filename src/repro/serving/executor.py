@@ -364,6 +364,14 @@ class EngineExecutor:
                           "timed_out": any(
                               getattr(r, "cancelled", False) for r in ers)})
 
+    def step_latency(self, variant: Variant) -> float:
+        """Measured wall seconds of one decode step on the variant's
+        engine, queue excluded (``ServingEngine.step_seconds``): what the
+        overload test sets against one step of the profile's t(b). 0.0
+        before the engine's first step."""
+        eng = self.engines.get(variant.name)
+        return eng.step_seconds if eng is not None else 0.0
+
     def _observe(self, variant: Variant, n: int, dt: float) -> None:
         """Fold one synthetic-batch measurement into the t(b) fit."""
         obs = self.observations.setdefault(variant.name, {})

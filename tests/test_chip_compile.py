@@ -103,3 +103,21 @@ def test_kernel_compiles_for_v5e(kernel, widths, one_chip, no_compile_cache):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         f"{kernel} compiled without its Pallas kernel"
+
+
+def test_long_causal_prefill_attention_fits_for_v5e(one_chip, no_compile_cache):
+    """Causal prefill attention at Yi-9B's widths (4 KV heads of 128, 8
+    query heads each) for 8 rows of a 2,048-token bucket: chosen by shape,
+    the chunked path streams its scores, where the dense form's float32
+    (8, 4, 8, 2048, 2048) scores alone take 4 GiB of the chip's 16."""
+    from repro.models.layers import attention_core
+    B, S, K, G, D = 8, 2048, 4, 8, 128
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    compiled = jax.jit(lambda q, k, v: attention_core(
+        q, k, v, causal=True)).lower(
+        sds(B, S, K, G, D), sds(B, S, K, D), sds(B, S, K, D)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2**28, temp
