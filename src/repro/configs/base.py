@@ -49,7 +49,12 @@ class ArchConfig:
     # --- numerics / implementation ---
     dtype: str = "bfloat16"
     param_dtype: str = "bfloat16"
-    attention_impl: str = "xla"      # "xla" | "pallas" | "pallas_interpret"
+    # prefill's (and the paged pool's) attention path: "xla" | "pallas" |
+    # "pallas_interpret". A serving engine's decode over the contiguous
+    # cache takes the length-aware Pallas kernel on a TPU whatever this
+    # says (in interpret mode under "pallas_interpret"); see
+    # models.layers.attention_core
+    attention_impl: str = "xla"
     # weight-only quantization of the projection matmuls (dense family):
     # "none" | "int8" (XLA dequant) | "int8_pallas" | "int8_pallas_interpret"
     # (the Pallas dequant-GEMM kernel / its interpret-mode build)

@@ -30,11 +30,19 @@ the pool carries one extra *trash page* at index ``n_pages - 1`` — equal
 to the block table's sentinel value — so writes by inactive slots land
 harmlessly in a page no block table references for live reads (stale
 trash contents sit behind ``valid_len`` and mask to exact zeros).
+
+``stacked_decode_attention`` runs the block-table kernel over the
+contiguous decode carry: a stacked (L, B, S, K, D) cache reshapes for free
+into a pool of (ps, K, D) blocks, the grid runs only the live slots, and
+each slot's table walks only the blocks below its valid length. A block
+index repeated across consecutive grid steps is not fetched again, so the
+blocks past a slot's length cost no DMA, and a slot with nothing live
+costs none at all.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -173,9 +181,9 @@ def _paged_scratch(K: int, G: int, D: int):
 # fetched once per slot in a single DMA.
 
 
-def _paged_decode_kernel(vlen_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *,
-                         page_size: int, n_t_blocks: int, sm_scale: float):
+def _paged_decode_kernel(off_ref, vlen_ref, bt_ref, slot_ref, q_ref, k_ref,
+                         v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                         page_size: int, sm_scale: float):
     b = pl.program_id(0)
     tj = pl.program_id(1)
 
@@ -195,9 +203,38 @@ def _paged_decode_kernel(vlen_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
             _online_softmax_step(q, k, v, t_start, valid_len,
                                  m_scr, l_scr, acc_scr, h, sm_scale)
 
-    @pl.when(tj == n_t_blocks - 1)
+    @pl.when(tj == pl.num_programs(1) - 1)
     def _finish():
         _finish_heads(o_ref, l_scr, acc_scr)
+
+
+def _paged_call(q, k_pool, v_pool, table, vlen, slots, grid, interpret,
+                offset=0):
+    """The block-table flash-decode over ``grid`` = (rows, blocks): grid
+    row ``i`` serves slot ``slots[i]`` (its query and output) at valid
+    length ``vlen[i]``, walking pool pages ``offset + table[i, :]``. Either
+    grid bound may be a traced scalar."""
+    B, K, G, D = q.shape
+    page_size = k_pool.shape[1]
+    row = pl.BlockSpec((1, K, G, D),
+                       lambda i, j, off, vl, bt, sl: (sl[i], 0, 0, 0))
+    page = pl.BlockSpec((1, page_size, K, D),
+                        lambda i, j, off, vl, bt, sl:
+                        (off[0] + bt[i, j], 0, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_paged_decode_kernel, page_size=page_size,
+                          sm_scale=D ** -0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=grid,
+            in_specs=[row, page, page],
+            out_specs=row,
+            scratch_shapes=_paged_scratch(K, G, D),
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
+        interpret=interpret,
+    )(jnp.reshape(jnp.asarray(offset, jnp.int32), (1,)), vlen, table, slots,
+      q, k_pool, v_pool)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -211,11 +248,12 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     cache layout of ``repro.models.kvcache``; block_table: (B, P) page ids
     (sentinel entries >= n_pages clamp to the last page and are masked by
     ``valid_len``); valid_len: scalar or (B,) per-slot valid lengths over
-    the slot's *logical* sequence of P * page_size positions.
+    the slot's *logical* sequence of P * page_size positions. A slot of
+    valid length 0 computes nothing and returns zeros.
 
     Returns (B, K, G, D).
     """
-    B, K, G, D = q.shape
+    B = q.shape[0]
     n_pages, page_size = k_pool.shape[:2]
     P = block_table.shape[1]
     if valid_len is None:
@@ -224,29 +262,103 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         valid_len = jnp.broadcast_to(
             jnp.asarray(valid_len, jnp.int32), (B,))
     bt = jnp.clip(block_table.astype(jnp.int32), 0, n_pages - 1)
+    return _paged_call(q, k_pool, v_pool, bt, valid_len,
+                       jnp.arange(B, dtype=jnp.int32), (B, P), interpret)
 
-    kernel = functools.partial(_paged_decode_kernel, page_size=page_size,
-                               n_t_blocks=P, sm_scale=D ** -0.5)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, P),
-            in_specs=[
-                pl.BlockSpec((1, K, G, D),
-                             lambda b, j, vlen, bt: (b, 0, 0, 0)),
-                pl.BlockSpec((1, page_size, K, D),
-                             lambda b, j, vlen, bt: (bt[b, j], 0, 0, 0)),
-                pl.BlockSpec((1, page_size, K, D),
-                             lambda b, j, vlen, bt: (bt[b, j], 0, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, K, G, D),
-                                   lambda b, j, vlen, bt: (b, 0, 0, 0)),
-            scratch_shapes=_paged_scratch(K, G, D),
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
-        interpret=interpret,
-    )(valid_len, bt, q, k_pool, v_pool)
+
+# Largest KV tile the stacked decode fetches in one DMA: double-buffered K
+# and V then hold 4 MiB of VMEM.
+STACKED_TILE_MAX_BYTES = 1 << 20
+
+
+def stacked_block_len(max_len: int, n_kv: int, head_dim: int,
+                      itemsize: int) -> int:
+    """Block length of the stacked decode for a (.., max_len, n_kv,
+    head_dim) cache: the largest power of two that divides ``max_len``,
+    leaves at least four blocks per slot (a short live prefix reads a
+    quarter of the slot or less) and keeps one (block, K, D) tile, with D
+    padded to the 128 lanes, within ``STACKED_TILE_MAX_BYTES``. phi3-mini
+    (K 32, D 96, 512 positions, bf16) gets 128; Yi-9B (K 4, D 128, 2,048)
+    gets 512."""
+    row = n_kv * -(-head_dim // 128) * 128 * itemsize
+    ps = 1
+    while (max_len % (2 * ps) == 0 and 2 * ps <= max(max_len // 4, 1)
+           and 2 * ps * row <= STACKED_TILE_MAX_BYTES):
+        ps *= 2
+    return ps
+
+
+class StackedPlan(NamedTuple):
+    """One decode step's walk of the stacked cache, the same for every
+    layer: built once per step (``stacked_decode_plan``), outside the layer
+    scan, so a layer adds nothing to the kernel call but its index."""
+    valid_len: jax.Array   # (B,) positions each slot attends
+    rows: jax.Array        # (B,) slot each grid row serves
+    row_len: jax.Array     # (B,) valid length of each grid row's slot
+    table: jax.Array       # (B, P) layer-0 block of each grid row, column
+    n_rows: jax.Array      # grid rows that run
+    n_cols: jax.Array      # grid columns that run
+    positions_read: jax.Array  # KV positions a layer's call fetches: the
+                               # live slots' blocks, in positions
+
+
+def stacked_decode_plan(valid_len: jax.Array, k_stack: Any) -> StackedPlan:
+    """The grid of ``stacked_decode_attention`` for per-slot ``valid_len``
+    (B,) over a stacked (L, B, S, K, D) cache (``k_stack``: the array or its
+    shape and dtype), in blocks of ``stacked_block_len``.
+
+    The grid's rows run the live slots only, in slot order, and its columns
+    only as far as the longest of them, so a slot with nothing live fetches
+    nothing. Row ``i`` walks its slot's blocks in the (L * B * P, block, K,
+    D) view of the stack, layer 0's ids (layer ``l`` adds ``l * B * P``),
+    up to the slot's last live block, and repeats that block to the end of
+    the row, where Pallas fetches nothing."""
+    _L, B, S, K, D = k_stack.shape
+    block = stacked_block_len(S, K, D, jnp.dtype(k_stack.dtype).itemsize)
+    P = S // block
+    vlen = jnp.asarray(valid_len, jnp.int32)
+    nblk = (vlen + block - 1) // block                          # (B,)
+    live = nblk > 0
+    j = jnp.arange(P, dtype=jnp.int32)
+    table = jnp.arange(B, dtype=jnp.int32)[:, None] * P + jnp.minimum(
+        j[None, :], jnp.maximum(nblk - 1, 0)[:, None])
+    rows = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    return StackedPlan(vlen, rows, vlen[rows], table[rows],
+                       jnp.maximum(jnp.sum(live.astype(jnp.int32)), 1),
+                       jnp.maximum(jnp.max(nblk), 1),
+                       jnp.sum(nblk) * block)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def stacked_decode_attention(q: jax.Array, k_stack: jax.Array,
+                             v_stack: jax.Array, layer: Any,
+                             plan: StackedPlan, *,
+                             interpret: bool = False) -> jax.Array:
+    """Decode attention of one layer of a stacked contiguous KV cache,
+    reading only each slot's blocks below its valid length.
+
+    q: (B, K, G, D), one query token per slot; k_stack/v_stack: (L, B, S,
+    K, D), the whole carry of a decode layer scan; ``layer``: scalar layer
+    index; ``plan``: the step's ``stacked_decode_plan`` of each slot's
+    valid length (``pos + 1`` for a live slot, 0 for one with nothing
+    live, whose output is zeros).
+
+    The stack is viewed as a (L * B * S / block, block, K, D) page pool, a
+    reshape that moves no bytes, and the block-table kernel of
+    ``paged_decode_attention`` walks it, layer ``layer``'s blocks found by
+    an offset in the index map.
+
+    Returns (B, K, G, D).
+    """
+    L, B, S, K, D = k_stack.shape
+    P = plan.table.shape[1]
+    pool_shape = (L * B * P, S // P, K, D)
+    out = _paged_call(q, k_stack.reshape(pool_shape),
+                      v_stack.reshape(pool_shape), plan.table, plan.row_len,
+                      plan.rows, (plan.n_rows, plan.n_cols), interpret,
+                      offset=layer * (B * P))
+    # a slot no grid row served holds whatever the buffer held
+    return jnp.where((plan.valid_len > 0)[:, None, None, None], out, 0)
 
 
 def _fused_paged_decode_kernel(vlen_ref, wblk_ref, woff_ref, bt_ref,

@@ -289,6 +289,37 @@ def decode_attention_splitk(q, kc, vc, valid_len, ctx) -> jax.Array:
 DENSE_SCORES_MAX_BYTES = 1 << 30
 
 
+def _sharded() -> bool:
+    from repro.distributed.parallel import get_activation_sharding
+    ctx = get_activation_sharding()
+    return ctx is not None and ctx.mesh is not None
+
+
+def _stacked_route(impl: str, kernel, fallback, *args):
+    """Where work over the stacked contiguous decode carry runs (the rule
+    ``attention_core`` documents): ``kernel(*args, interpret=...)`` where
+    the program is lowered for a TPU, or in interpret mode under
+    ``impl="pallas_interpret"``; ``fallback(*args)`` on other platforms and
+    under an activation-sharding mesh (the kernel reads one device's whole
+    cache)."""
+    if _sharded():
+        return fallback(*args)
+    if impl == "pallas_interpret":
+        return kernel(*args, interpret=True)
+    return lax.platform_dependent(*args, tpu=kernel, default=fallback)
+
+
+def stacked_decode_reads(plan, k_stack: Any, impl: str) -> jax.Array:
+    """KV positions one decode step's attention over the stacked carry
+    ``k_stack`` (L, B, S, K, D) reads, summed over slots, where
+    ``attention_core`` routes it under ``plan`` (its ``layer`` case): the
+    kernel's live blocks, else all S positions of all B slots."""
+    _L, B, S = k_stack.shape[:3]
+    return _stacked_route(
+        impl, lambda plan, interpret=False: plan.positions_read,
+        lambda plan: jnp.int32(B * S), plan)
+
+
 def attention_core(
     q: jax.Array,                 # (B, S, K, G, D)
     k: jax.Array,                 # (B, T, K, D)
@@ -299,11 +330,51 @@ def attention_core(
     kv_valid_len: Optional[Any] = None,   # mask kv positions >= this;
                                           # scalar, or (B,) per-sequence
     impl: str = "xla",
+    layer: Optional[Any] = None,  # k/v are a stacked (L, B, T, K, D)
+                                  # carry; attend over this layer of it,
+                                  # kv_valid_len then a StackedPlan
 ) -> jax.Array:
-    """Grouped-query attention. Returns (B, S, K, G, D)."""
-    # Per-sequence valid lengths (continuous batching: each batch slot is at
-    # a different decode position) only lower through the plain XLA path —
-    # the Pallas/split-K kernels take a single scalar length.
+    """Grouped-query attention. Returns (B, S, K, G, D).
+
+    Routing, by what the call shows:
+
+    * Decode over the stacked contiguous carry (``layer`` given, one query
+      token, ``kv_valid_len`` the step's ``stacked_decode_plan`` of the
+      per-slot lengths, built once outside the layer scan; what it reads:
+      ``stacked_decode_reads``) runs the
+      length-aware Pallas kernel
+      (``kernels.decode_attention.stacked_decode_attention``) where the
+      program is lowered for a TPU, and in interpret mode under
+      ``impl="pallas_interpret"`` anywhere: each slot reads only its KV
+      blocks below its valid length, and a slot of length 0 reads nothing
+      (its output is zeros). On other platforms, or under an
+      activation-sharding mesh, the layer's slice is cut out and the rules
+      below apply to it, with the plan's per-slot lengths.
+    * Other per-slot valid lengths lower through the plain XLA path; the
+      Pallas and split-K kernels take a single scalar length.
+    * Otherwise ``impl`` chooses prefill's path: ``"xla"``, ``"xla_chunked"``
+      or a Pallas flash kernel; a causal self-attention whose dense float32
+      scores would pass ``DENSE_SCORES_MAX_BYTES`` streams them in blocks
+      whatever the impl string.
+    """
+    if layer is not None:
+        assert q.shape[1] == 1, "the stacked carry takes one query token"
+
+        def sliced(q, k, v, layer, plan):
+            return attention_core(
+                q, lax.dynamic_index_in_dim(k, layer, keepdims=False),
+                lax.dynamic_index_in_dim(v, layer, keepdims=False),
+                causal=causal, q_offset=q_offset,
+                kv_valid_len=plan.valid_len, impl=impl)
+
+        def kernel(q, k, v, layer, plan, interpret=False):
+            from repro.kernels.decode_attention import \
+                stacked_decode_attention
+            return stacked_decode_attention(q[:, 0], k, v, layer, plan,
+                                            interpret=interpret)[:, None]
+
+        return _stacked_route(impl, kernel, sliced, q, k, v, layer,
+                              kv_valid_len)
     vec_valid = kv_valid_len is not None and jnp.ndim(kv_valid_len) > 0
     if vec_valid:
         impl = "xla"

@@ -25,6 +25,11 @@ Serving extensions (used by the continuous-batching engine):
     Supported by the dense/moe/hybrid/vlm/audio decode paths (audio
     carries its true encoder length per slot as an ``enc_len`` cache leaf
     and masks cross-attention by it).
+  * the dense ``decode`` takes an optional ``plan``, the step's
+    ``stacked_decode_plan`` of each slot's valid length: a contiguous
+    cache then attends each slot over its length (a dead slot over none),
+    through the length-aware decode kernel on a TPU
+    (``transformer.decode_dense``).
   * ``empty_state(batch, max_len)`` returns the decode cache of a
     sequence that has seen no tokens — the slot-reset seam the serving
     engine uses for chunked prefill and in-segment admission (all-zeros
@@ -101,7 +106,8 @@ def build_model(cfg: ArchConfig, parallel=None) -> Model:
             forward=lambda p, b: T.forward_dense(cfg, p, b["tokens"]),
             prefill=lambda p, b: T.prefill_dense(cfg, p, b["tokens"],
                                                  length=b.get("length")),
-            decode=lambda p, c, t, pos: T.decode_dense(cfg, p, c, t, pos),
+            decode=lambda p, c, t, pos, plan=None: T.decode_dense(
+                cfg, p, c, t, pos, plan=plan),
             cache_shapes=cs,
             empty_state=_zeros_empty_state(cs),
             decode_window=lambda p, c, t, pos: T.decode_window_dense(

@@ -249,15 +249,24 @@ def _decode_block(x, blk, kc, vc, pos, cfg: ArchConfig, bt=None):
 
 
 def decode_dense(cfg: ArchConfig, params: Params, cache, token: jax.Array,
-                 pos) -> Tuple[jax.Array, Any]:
+                 pos, plan: Optional[Any] = None) -> Tuple[jax.Array, Any]:
     """serve_step: one new token against the cache. token: (B,1) int32.
 
     ``cache`` may carry a ``"bt"`` block table, in which case its k/v
     leaves are shared page pools (see ``repro.models.kvcache``), scanned
     layer by layer as xs/ys. A contiguous (L,B,Smax,K,D) cache instead
-    rides in the scan carry: layer ``l`` writes its token's rows in place
-    and attends over its slice read back after the write, so no step
-    copies a layer slice, or the stack, whole."""
+    rides in the scan carry: layer ``l`` writes its token's rows in place,
+    then attends over layer ``l`` of the carry, so no step copies a layer
+    slice, or the stack, whole.
+
+    ``plan``: optional ``kernels.decode_attention.stacked_decode_plan`` of
+    each slot's valid length for this step (a serving engine's: ``pos + 1``
+    for a live slot, 0 for a dead one). With it, a contiguous decode
+    attends each slot over its valid length through ``L.attention_core``'s
+    stacked-carry route (the length-aware kernel on a TPU), and a dead
+    slot's logits are meaningless. Without it every slot attends over
+    ``pos + 1`` through XLA, which the speculative verifier's bit-identity
+    rests on."""
     dtype = jnp.dtype(cfg.dtype)
     bt = cache.get("bt")
     x = L.embed_tokens(token, params["embed"], dtype)
@@ -268,9 +277,15 @@ def decode_dense(cfg: ArchConfig, params: Params, cache, token: jax.Array,
             blk, layer = xs
             q, k, v = _decode_qkv(h, blk, pos, cfg)
             ks, vs = KV.update_stacked_layer_cache(ks, vs, k, v, layer, pos)
-            o = _decode_attend(
-                q, lax.dynamic_index_in_dim(ks, layer, keepdims=False),
-                lax.dynamic_index_in_dim(vs, layer, keepdims=False), pos, cfg)
+            if plan is None:
+                o = _decode_attend(
+                    q, lax.dynamic_index_in_dim(ks, layer, keepdims=False),
+                    lax.dynamic_index_in_dim(vs, layer, keepdims=False),
+                    pos, cfg)
+            else:
+                o = L.attention_core(q, ks, vs, causal=False, layer=layer,
+                                     kv_valid_len=plan,
+                                     impl=cfg.attention_impl)
             return (_decode_out(h, o, blk, cfg), ks, vs), None
 
         n_layers = cache["k"].shape[0]

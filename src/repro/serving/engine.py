@@ -193,7 +193,9 @@ from jax import lax
 
 from repro.configs.base import ArchConfig
 from repro.core import spans
+from repro.kernels.decode_attention import stacked_decode_plan
 from repro.models import kvcache as KV
+from repro.models.layers import stacked_decode_reads
 from repro.models.model import Model, build_model
 
 
@@ -725,6 +727,12 @@ class ServingEngine:
             "verify_traces": 0,
             "swap_out": 0, "swap_in": 0, "swap_fallbacks": 0,
             "swap_bytes": 0,
+            # KV positions decode attention fetched, summed over decode
+            # steps and slots: the length-aware kernel's blocks below each
+            # live slot's length (none for a dead slot), counted in the
+            # decode loop; and slots x max_len per step, which every other
+            # decode path reads
+            "decode_kv_positions_read": 0, "decode_kv_positions_full": 0,
         }
         # ----- host-RAM KV swap-out ------------------------------------
         # ``swap="host"`` preserves a preemption victim's live KV pages in
@@ -894,6 +902,14 @@ class ServingEngine:
         # kernels.decode_attention.fused_paged_decode_attention).
         self._view_decode = self._paged and \
             not attn_impl.startswith("pallas")
+        # The dense decode over a contiguous cache is handed each step's
+        # plan of the live slots' lengths, so it attends only their
+        # positions: through the length-aware kernel where
+        # ``attention_core`` routes there (a TPU, or pallas_interpret). A
+        # speculating engine keeps XLA decode for every slot: its
+        # verifier's bit-identity with plain decode rests on it.
+        self._live_decode = model.cfg.family == "dense" and \
+            self._spec is None and not self._paged
         # block-table upload coalescing: the device copy is invalidated
         # only when a host-side write actually changes self._bt, so
         # steady-state segments reuse the resident array
@@ -1101,7 +1117,8 @@ class ServingEngine:
             return self._decode_fn
         model, steps, slots = self.model, self.decode_block, self.max_batch
         paged, max_len = self._paged, self.max_len
-        view = self._view_decode
+        view, live_decode = self._view_decode, self._live_decode
+        attn_impl = model.cfg.attention_impl
         R = max(self.stage_slots, 1)      # device ring capacity (static)
         max_comps = slots + R             # completion-log capacity
         baxes, saxes = self._batch_axes, self._seq_axes
@@ -1146,7 +1163,17 @@ class ServingEngine:
                 active = rem > 0
                 dcache = dict(cache, bt=bt_c) if (paged and not view) \
                     else cache
-                logits, dcache = model.decode(params, dcache, tok, pos)
+                if live_decode:
+                    # one walk of the cache for every layer of this step
+                    plan = stacked_decode_plan(jnp.where(active, pos + 1, 0),
+                                               cache["k"])
+                    logits, dcache = model.decode(params, dcache, tok, pos,
+                                                  plan=plan)
+                    kv_read = stacked_decode_reads(plan, cache["k"],
+                                                   attn_impl)
+                else:
+                    logits, dcache = model.decode(params, dcache, tok, pos)
+                    kv_read = slots * max_len
                 if paged and not view:
                     dcache = {k: v for k, v in dcache.items() if k != "bt"}
                 cache = dcache
@@ -1200,7 +1227,8 @@ class ServingEngine:
                     head=head + jnp.minimum(nfin, jnp.maximum(avail, 0)),
                     comp_slot=comp_slot, comp_step=comp_step,
                     comp_adm=comp_adm, n_comp=st["n_comp"] + nfin,
-                    busy=st["busy"] + jnp.sum(active.astype(jnp.int32)))
+                    busy=st["busy"] + jnp.sum(active.astype(jnp.int32)),
+                    kv_read=st["kv_read"] + kv_read)
                 if paged:
                     new["bt"] = jnp.where(adm[:, None],
                                           jnp.take(ring_bt, src, axis=0),
@@ -1221,7 +1249,8 @@ class ServingEngine:
                        comp_slot=jnp.zeros((max_comps,), jnp.int32),
                        comp_step=jnp.zeros((max_comps,), jnp.int32),
                        comp_adm=jnp.zeros((max_comps,), jnp.int32),
-                       n_comp=jnp.int32(0), busy=jnp.int32(0))
+                       n_comp=jnp.int32(0), busy=jnp.int32(0),
+                       kv_read=jnp.int32(0))
             if paged:
                 st0["bt"] = jnp.asarray(bt)
             if view:
@@ -1243,7 +1272,7 @@ class ServingEngine:
             return (out_cache, st["tok"], st["pos"], st["rem"],
                     st["plen"], st["pbuf"], st["out"], st["comp_slot"],
                     st["comp_step"], st["comp_adm"], st["n_comp"],
-                    st["busy"], st["i"])
+                    st["busy"], st["i"], st["kv_read"])
 
         if paged:
             self._decode_fn = jax.jit(decode_segment)
@@ -2159,6 +2188,10 @@ class ServingEngine:
         self.stats["decode_dispatches"] += 1
         self.stats["decode_steps"] += k + 1
         self.stats["spec_rounds"] += 1
+        # the verify window and the draft read every position
+        full = (k + 1) * self.max_batch * self.max_len
+        self.stats["decode_kv_positions_full"] += full
+        self.stats["decode_kv_positions_read"] += full
         emitted = 0
         replay: List[int] = []
         seq_verify = self.model.decode_window is None
@@ -2302,7 +2335,7 @@ class ServingEngine:
         t_seg = time.perf_counter()
         (self._cache, self._tok, self._pos, self._rem, self._plen,
          self._pbuf, out, comp_slot, comp_step, comp_adm, n_comp,
-         busy_steps, n_steps) = decode(*args)
+         busy_steps, n_steps, kv_read) = decode(*args)
         self.stats["decode_dispatches"] += 1
         with spans.span("engine.sync"):
             out_np = np.asarray(out)                 # the one host sync
@@ -2312,6 +2345,7 @@ class ServingEngine:
             n_comp = int(n_comp)
             n_steps = int(n_steps)
             busy_steps = int(busy_steps)
+            kv_read = int(kv_read)
         if n_steps:
             # EWMA per-step wall time (all slots advance in lockstep):
             # feeds the slack policy's remaining-service estimate
@@ -2320,20 +2354,24 @@ class ServingEngine:
                 else 0.8 * self._step_est + 0.2 * per
         with spans.span("engine.harvest"):
             self._harvest(out_np, comp_slot, comp_step, comp_adm, n_comp,
-                          n_steps, busy_steps)
+                          n_steps, busy_steps, kv_read)
         return n_steps
 
     def _harvest(self, out_np: np.ndarray, comp_slot: np.ndarray,
                  comp_step: np.ndarray, comp_adm: np.ndarray, n_comp: int,
-                 n_steps: int, busy_steps: int) -> None:
+                 n_steps: int, busy_steps: int, kv_read: int) -> None:
         """Book a finished decode segment on the host: counters, then the
         completion log (retire, in-segment seats), emitted tokens, the
-        streaming flush, and prefix-page registration."""
+        streaming flush, and prefix-page registration. ``kv_read``: the KV
+        positions the segment's decode attention read."""
         self._slot_pos = np.asarray(self._pos).astype(np.int64)
         self.stats["decode_steps"] += n_steps
         self.stats["busy_slot_steps"] += busy_steps
         self.stats["bubble_slot_steps"] += \
             n_steps * self.max_batch - busy_steps
+        self.stats["decode_kv_positions_read"] += kv_read
+        self.stats["decode_kv_positions_full"] += \
+            n_steps * self.max_batch * self.max_len
         now = time.perf_counter()
         # completion log, in segment order: each record closes the slot's
         # current occupant over out[slot, consumed:step+1]; an "admitted"

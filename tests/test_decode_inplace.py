@@ -13,7 +13,11 @@ scan and writes only each layer's new token. Pinned here:
   its loop (the per-layer form copied the stacked K and V into the loop
   state every step, and rebuilt them from its ys);
 * warm-up runs the decode segment against the live state and leaves it as
-  it was: serving after ``warmup()`` gives what a cold engine gives.
+  it was: serving after ``warmup()`` gives what a cold engine gives;
+* through the real segment, the length-aware decode kernel (interpret mode
+  on the CPU) serves the tokens the XLA decode serves, on phi3- and
+  Yi-shaped models, and the engine's read counters count the positions of
+  each live slot's blocks; a speculating engine keeps the XLA decode.
 """
 import re
 
@@ -34,6 +38,8 @@ pytestmark = pytest.mark.slow
 from repro.serving.engine import Request, ServingEngine  # noqa: E402
 
 ARCH_IDS = ["phi3-mini-3.8b", "llama3.2-1b"]
+# reduced phi3 (MHA, G 1) and Yi (GQA, G 2)
+KERNEL_ARCH_IDS = ["phi3-mini-3.8b", "yi-9b"]
 _BUILT = {}
 
 
@@ -219,3 +225,90 @@ def test_serving_after_warmup_matches_cold_engine(page_size):
     np.testing.assert_array_equal(np.asarray(got.tokens),
                                   np.asarray(want.tokens))
     assert warm.stats["decode_traces"] == 1
+
+
+def _kv_blocks_read(reqs, block):
+    """Positions the length-aware decode fetches over a whole serve with
+    prefill admission: request ``r`` decodes ``max_new - 1`` steps after
+    its prefill, attending ``len(prompt) + 1`` positions at the first,
+    one more at each next, in blocks of ``block``."""
+    n = 0
+    for r in reqs:
+        vlen = len(r.prompt) + 1 + np.arange(r.max_new_tokens - 1)
+        n += int((-(-vlen // block) * block).sum())
+    return n
+
+
+@pytest.mark.parametrize("arch", KERNEL_ARCH_IDS)
+def test_length_aware_decode_engine_matches_xla_engine(arch):
+    import dataclasses
+    cfg = ARCHS[arch].reduced()
+    kw = dict(max_batch=3, max_len=64, decode_block=4, min_bucket=4)
+    engines = {}
+    for impl in ("xla", "pallas_interpret"):
+        model = build_model(dataclasses.replace(cfg, attention_impl=impl))
+        params = model.init(jax.random.PRNGKey(0))
+        eng = ServingEngine(model, params, **kw)
+        reqs = _stream(cfg)
+        eng.serve(reqs)
+        engines[impl] = (eng, reqs)
+    (x_eng, x_reqs), (k_eng, k_reqs) = engines["xla"], \
+        engines["pallas_interpret"]
+    for a, b in zip(x_reqs, k_reqs):
+        assert len(a.tokens) == a.max_new_tokens
+        np.testing.assert_array_equal(np.asarray(a.tokens),
+                                      np.asarray(b.tokens),
+                                      err_msg=f"rid={a.rid}")
+    # the kernel reads each live slot's blocks below its length, in
+    # blocks of 16 here (a quarter of the 64 positions); XLA reads all
+    full = k_eng.stats["decode_steps"] * 3 * 64
+    assert k_eng.stats["decode_kv_positions_full"] == full
+    assert k_eng.stats["decode_kv_positions_read"] == \
+        _kv_blocks_read(k_reqs, 16) < full
+    assert x_eng.stats["decode_kv_positions_read"] == \
+        x_eng.stats["decode_kv_positions_full"] == \
+        x_eng.stats["decode_steps"] * 3 * 64
+
+
+def test_speculating_engine_keeps_xla_decode():
+    _cfg, model, params = _build("llama3.2-1b")
+    eng = ServingEngine(model, params, max_batch=3, max_len=64,
+                        decode_block=4, min_bucket=4,
+                        speculate=(model, params), spec_k=2)
+    assert not eng._live_decode
+    eng.serve(_stream(_cfg))
+    assert eng.stats["decode_kv_positions_read"] == \
+        eng.stats["decode_kv_positions_full"] > 0
+
+
+def test_length_aware_decode_counts_in_segment_seats():
+    """Requests seated inside the segment (``stage_slots``) feed their
+    prompt through the decode loop from position 0: through the kernel they
+    decode what the XLA engine decodes, and each such seat reads the blocks
+    of positions 1 .. len(prompt) on top of what a prefilled request
+    reads."""
+    import dataclasses
+    cfg = ARCHS["phi3-mini-3.8b"].reduced()
+    kw = dict(max_batch=2, max_len=64, decode_block=8, min_bucket=4,
+              stage_slots=4)
+    plen = 6
+    max_new = np.random.default_rng(11).integers(2, 9, size=6)
+    runs = {}
+    for impl in ("xla", "pallas_interpret"):
+        model = build_model(dataclasses.replace(cfg, attention_impl=impl))
+        eng = ServingEngine(model, model.init(jax.random.PRNGKey(0)), **kw)
+        reqs = [Request(rid=i, prompt=np.arange(i, i + plen, dtype=np.int32),
+                        max_new_tokens=int(n))
+                for i, n in enumerate(max_new)]
+        eng.serve(reqs)
+        runs[impl] = (eng, reqs)
+    (_, x_reqs), (k_eng, k_reqs) = runs["xla"], runs["pallas_interpret"]
+    for a, b in zip(x_reqs, k_reqs):
+        np.testing.assert_array_equal(np.asarray(a.tokens),
+                                      np.asarray(b.tokens),
+                                      err_msg=f"rid={a.rid}")
+    seats = k_eng.stats["inseg_admissions"]
+    assert seats > 0
+    # positions 1 .. 6 fall in the first block of 16
+    assert k_eng.stats["decode_kv_positions_read"] == \
+        _kv_blocks_read(k_reqs, 16) + seats * plen * 16

@@ -256,3 +256,80 @@ def test_fused_paged_decode_attention(case, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32)[:B - 1],
                                np.asarray(o_ref, np.float32)[:B - 1],
                                **tol(dtype))
+
+
+STACKED_CASES = {
+    # (L, B, S, K, G, D): phi3-mini's and Yi-9B's attention widths at
+    # their served lengths, few layers and slots
+    "phi3": (2, 6, 512, 32, 1, 96),
+    "yi": (2, 6, 2048, 4, 8, 128),
+}
+
+
+@pytest.mark.parametrize("layer", ["first", "last"])
+@pytest.mark.parametrize("widths", sorted(STACKED_CASES))
+def test_stacked_decode_attention_matches_masked_xla(widths, layer):
+    """The length-aware decode over a stacked (L, B, S, K, D) carry ==
+    attention_core's masked XLA attention over the layer's slice, for
+    slots of length 1, a block edge +- 1 and S; a dead slot (length 0)
+    returns zeros and is not compared."""
+    from repro.kernels.decode_attention import (stacked_block_len,
+                                                stacked_decode_attention,
+                                                stacked_decode_plan)
+    from repro.models.layers import attention_core
+
+    L, B, S, K, G, D = STACKED_CASES[widths]
+    ps = stacked_block_len(S, K, D, 2)
+    assert 1 < ps < S and S % ps == 0
+    layer = 0 if layer == "first" else L - 1
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(L * S + K), 3)
+    ks = jax.random.normal(k1, (L, B, S, K, D), jnp.float32).astype(
+        jnp.bfloat16)
+    vs = jax.random.normal(k2, (L, B, S, K, D), jnp.float32).astype(
+        jnp.bfloat16)
+    q = jax.random.normal(k3, (B, K, G, D), jnp.float32).astype(jnp.bfloat16)
+    valid = jnp.asarray([0, 1, ps - 1, ps, ps + 1, S], jnp.int32)
+    want = attention_core(q[:, None], ks[layer], vs[layer], causal=False,
+                          kv_valid_len=valid)[:, 0]
+    live = np.asarray(valid) > 0
+    out = stacked_decode_attention(q, ks, vs, layer,
+                                   stacked_decode_plan(valid, ks),
+                                   interpret=True)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(want, np.float32)[live],
+                               rtol=2e-2, atol=2e-2)
+    assert not np.asarray(out, np.float32)[~live].any()
+
+
+@pytest.mark.parametrize("valid", [
+    [0, 17, 0, 0, 64, 1],      # dead slots between and before live ones
+    [0, 0, 0, 0, 0, 0],        # nothing live: one row runs, computes nothing
+], ids=["mixed", "all-dead"])
+def test_stacked_plan_fetches_only_live_blocks(valid):
+    """Walked in grid order (row-major) over the grid that runs, the block
+    ids change, and so a block is fetched, only at each live slot's live
+    blocks: dead slots get no row, and blocks past a slot's length repeat
+    its last. The plan's ``positions_read`` counts those blocks."""
+    from repro.kernels.decode_attention import stacked_decode_plan
+    B, P, ps = 6, 4, 16
+    valid = jnp.asarray(valid, jnp.int32)
+    stack = jax.ShapeDtypeStruct((2, B, P * ps, 2, 16), jnp.float32)
+    plan = stacked_decode_plan(valid, stack)     # blocks of 16 here
+    n_live = int(np.sum(np.asarray(valid) > 0))
+    rows, cols = int(plan.n_rows), int(plan.n_cols)
+    assert (rows, cols) == ((3, 4) if n_live else (1, 1))
+    table = np.asarray(plan.table)[:rows, :cols]
+    order = table.reshape(-1)
+    fetched = 1 + int(np.sum(order[1:] != order[:-1]))
+    live_blocks = int(np.sum(-(-np.asarray(valid) // ps)))
+    assert fetched == max(live_blocks, 1)
+    assert int(plan.positions_read) == live_blocks * ps
+    # each row reads its own slot's blocks, at its slot's length, live
+    # slots first in slot order
+    for i in range(rows):
+        b = int(plan.rows[i])
+        assert int(plan.row_len[i]) == int(valid[b])
+        assert (table[i] // P == b).all()
+    assert sorted(np.asarray(plan.rows).tolist()) == list(range(B))
+    if n_live:
+        assert np.asarray(plan.rows)[:rows].tolist() == [1, 4, 5]
